@@ -4,7 +4,6 @@ plus the serving hot-path microbench (bulk prefill vs stepwise, donated
 chunked decode vs a per-token host-sync loop)."""
 from __future__ import annotations
 
-import os
 import time
 
 import numpy as np
@@ -464,86 +463,12 @@ def serving_zeroskip(smoke: bool = False) -> None:
              f"token_identical={identical}")
 
 
-# Runs in a subprocess: XLA_FLAGS must force the fake host devices before
-# jax initializes, and the parent bench session must keep its single device.
-# Prints "ROW name,us,derived" lines the parent re-emits.
-_SHARDED_CHILD = r'''
-import os
-from repro.launch.mesh import force_host_device_count
-force_host_device_count(8)   # replace any inherited flag, pre-backend-init
-import time
-import jax
-import numpy as np
-from benchmarks.common import tiny_serving_cfg
-from repro.models.registry import build
-from repro.serving.engine import ServingEngine
-
-cfg = tiny_serving_cfg()
-model = build(cfg)
-params = model.init(jax.random.PRNGKey(0))
-slots, block, max_len = 4, 8, 160
-iters = int(os.environ.get("SHARDED_BENCH_ITERS", "5"))
-for label, mesh in (("single", None),
-                    ("data2_model4", jax.make_mesh((2, 4), ("data", "model")))):
-    eng = ServingEngine(model, params, max_len=max_len, batch_slots=slots,
-                        decode_block=block, forms=True, mesh=mesh)
-    eng.prefill_slot(0, np.arange(16, dtype=np.int32) % cfg.vocab_size)
-    toks = np.zeros(slots, np.int32)
-    pos = np.full(slots, 16, np.int32)
-    temps = np.zeros(slots, np.float32)
-    eng.decode_chunk(toks, pos, temps)   # compile + warm
-    ts = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        eng.decode_chunk(toks, pos, temps)
-        ts.append(time.perf_counter() - t0)
-    ts.sort()
-    us = ts[len(ts) // 2] * 1e6
-    print(f"ROW serving.decode_forms_{label},{us:.2f},"
-          f"tok/s={slots * block / (us / 1e6):.0f};block={block};"
-          f"devices={jax.device_count()}", flush=True)
-'''
-
-
-def serving_sharded(smoke: bool = False) -> None:
-    """Mesh-sharded decode rows: the FORMS-compressed engine on a forced
-    8-device host mesh (data=2, model=4) next to its single-device baseline.
-
-    On CPU fake devices this measures partitioning overhead, not speedup —
-    the row pair exists so the perf trajectory catches regressions in the
-    sharded decode path (extra collectives, lost donation, resharding)."""
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["SHARDED_BENCH_ITERS"] = "3" if smoke else "5"
-    env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    try:
-        proc = subprocess.run([sys.executable, "-c", _SHARDED_CHILD],
-                              capture_output=True, text=True, env=env,
-                              cwd=root, timeout=900)
-    except subprocess.TimeoutExpired:
-        emit("serving.sharded_error", 0.0, "child timed out after 900s")
-        return
-    if proc.returncode != 0:
-        tail = (proc.stderr or proc.stdout or "")[-160:]
-        emit("serving.sharded_error", 0.0, tail.replace(",", ";"))
-        return
-    for line in proc.stdout.splitlines():
-        if line.startswith("ROW "):
-            name, us, derived = line[len("ROW "):].split(",", 2)
-            emit(name, float(us), derived)
-
-
 def run(smoke: bool = False) -> None:
     serving_hot_path(smoke=smoke)
     serving_paged(smoke=smoke)
     serving_speculative(smoke=smoke)
     serving_mixed_precision(smoke=smoke)
     serving_zeroskip(smoke=smoke)
-    serving_sharded(smoke=smoke)
     fragments = (8,) if smoke else (8, 16)
     kw = (dict(pretrain_steps=20, admm_steps=30, finetune_steps=10)
           if smoke else {})

@@ -29,6 +29,7 @@ import traceback
 
 from benchmarks import common
 from benchmarks.common import header
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -38,6 +39,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write all emitted rows to PATH as JSON")
     args = ap.parse_args()
+    use_compile_cache()
 
     from benchmarks import (bench_accuracy, bench_eic, bench_fps,
                             bench_fragment_size, bench_hw_model,

@@ -19,6 +19,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref, sparsity
 from repro.kernels.admm_polarize import admm_polarize as _admm_polarize_kernel
@@ -149,8 +150,9 @@ def _compact_matmul(x: jax.Array, mags: jax.Array, signs: jax.Array,
 
 def _pallas_polarized(x: jax.Array, mags: jax.Array, signs: jax.Array,
                       scale: jax.Array, *, m: int, bm: int, bn: int, bk: int,
-                      block_mask: Optional[jax.Array] = None) -> jax.Array:
-    """Pad to tile multiples, run the Pallas kernel, unpad."""
+                      skip: bool = False) -> jax.Array:
+    """Pad to tile multiples, run the Pallas kernel, unpad.  ``skip``
+    predicates each tile on the occupancy mask of the padded input."""
     M, K = x.shape
     N = mags.shape[1]
     bm_, bn_, bk_ = min(bm, M), min(bn, N), min(bk, K)
@@ -160,11 +162,36 @@ def _pallas_polarized(x: jax.Array, mags: jax.Array, signs: jax.Array,
     magsp = _pad_to(_pad_to(mags, 0, bk_), 1, bn_)
     signsp = _pad_to(_pad_to(signs, 0, bk_ // m), 1, bn_)
     scalep = _pad_to(scale.reshape(1, -1), 1, bn_)
-    if block_mask is True:  # sentinel: compute the mask from the padded x
-        block_mask = sparsity.block_mask(xp, bm_, bk_)
+    block_mask = sparsity.block_mask(xp, bm_, bk_) if skip else None
     out = _polarized_kernel(xp, magsp, signsp, scalep, block_mask, m=m,
                             bm=bm_, bn=bn_, bk=bk_, interpret=not on_tpu())
     return out[:M, :N]
+
+
+def _mesh_polarized(x: jax.Array, mags: jax.Array, signs: jax.Array,
+                    scale: jax.Array, **kernel_kw) -> jax.Array:
+    """:func:`_pallas_polarized`, per device when a mesh is active.
+
+    A Mosaic kernel is opaque to the SPMD partitioner, so under an active
+    ``distributed.sharding.parallel_context`` it runs inside ``shard_map``:
+    rows split over the batch axes and output columns over the model axes
+    (each where the dim divides), K whole on every device.  Weights placed
+    with a K shard (row-parallel layers) are re-laid out to column shards
+    by XLA before the call.
+    """
+    kernel = functools.partial(_pallas_polarized, **kernel_kw)
+    # imported here: distributed.sharding sits above kernels in the
+    # import graph (it imports repro.forms, which imports this module)
+    from repro.distributed.sharding import _checked_spec, current_context
+    ctx = current_context()
+    if ctx is None or ctx.mesh.size == 1:
+        return kernel(x, mags, signs, scale)
+    out = _checked_spec(("batch", "model"), (x.shape[0], mags.shape[1]), ctx)
+    b, n = (tuple(out) + (None, None))[:2]
+    return jax.shard_map(
+        kernel, mesh=ctx.mesh,
+        in_specs=(P(b, None), P(None, n), P(None, n), P(None, n)),
+        out_specs=out, check_vma=False)(x, mags, signs, scale)
 
 
 def polarized_matmul(
@@ -215,11 +242,10 @@ def polarized_matmul(
     if zero_skip == "compact":
         return _compact_matmul(
             x, mags, signs, scale, m, zero_skip_keep,
-            lambda x_, mg, sg, sc: _pallas_polarized(
+            lambda x_, mg, sg, sc: _mesh_polarized(
                 x_, mg, sg, sc, m=m, bm=bm, bn=bn, bk=bk))
-    return _pallas_polarized(
-        x, mags, signs, scale, m=m, bm=bm, bn=bn, bk=bk,
-        block_mask=True if zero_skip == "block" else None)
+    return _mesh_polarized(x, mags, signs, scale, m=m, bm=bm, bn=bn, bk=bk,
+                           skip=zero_skip == "block")
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,7 @@ accumulation in float32; the dequant scale is applied on the final K step.
 Zero-skipping (DESIGN.md §6g): pass ``block_mask`` — the (M/bm, K/bk) int32
 tile-occupancy mask from ``kernels.sparsity.block_mask`` — and the kernel
 predicates the sign-fold + MXU dot on the mask entry for the current
-(i, k) tile, read from SMEM.  An all-zero input tile contributes exactly 0
+(i, k) tile, scalar-prefetched into SMEM.  An all-zero input tile contributes exactly 0
 to the accumulator, so the skip is bit-identical to the dense kernel with
 the same tiling: accumulator init and the final scale step are unchanged,
 only the ``+= x @ w`` of dead tiles is elided.  This is the TPU analogue of
@@ -42,49 +42,35 @@ DEFAULT_BN = 128
 DEFAULT_BK = 512
 
 
-def _kernel(x_ref, mags_ref, signs_ref, scale_ref, out_ref, acc_ref, *, m: int,
-            n_k_blocks: int):
+def _kernel(*refs, m: int, n_k_blocks: int, skip: bool):
+    if skip:
+        mask_ref, *refs = refs
+    x_ref, mags_ref, signs_ref, scale_ref, out_ref, acc_ref = refs
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)                    # (bm, bk)
-    mags = mags_ref[...].astype(jnp.float32)              # (bk, bn)
-    signs = signs_ref[...].astype(jnp.float32)            # (bk//m, bn)
-    bk, bn = mags.shape
-    # fold the fragment signs into the magnitudes (VPU broadcast-multiply)
-    sgrid = jnp.broadcast_to(signs[:, None, :], (bk // m, m, bn)).reshape(bk, bn)
-    w = mags * sgrid
-    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
-
-    @pl.when(k_idx == n_k_blocks - 1)
-    def _finish():
-        scale = scale_ref[...].astype(jnp.float32)        # (1, bn)
-        out_ref[...] = (acc_ref[...] * scale).astype(out_ref.dtype)
-
-
-def _kernel_skip(x_ref, mags_ref, signs_ref, scale_ref, mask_ref, out_ref,
-                 acc_ref, *, m: int, n_k_blocks: int):
-    k_idx = pl.program_id(2)
-
-    @pl.when(k_idx == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # the only difference vs _kernel: the MAC is predicated on the tile
-    # occupancy bit, so dead input tiles never touch the MXU
-    @pl.when(mask_ref[0, 0] != 0)
     def _mac():
         x = x_ref[...].astype(jnp.float32)                # (bm, bk)
-        mags = mags_ref[...].astype(jnp.float32)          # (bk, bn)
+        # Mosaic has no uint8 -> float cast; widening through int32 is exact
+        mags = mags_ref[...].astype(jnp.int32).astype(jnp.float32)  # (bk, bn)
         signs = signs_ref[...].astype(jnp.float32)        # (bk//m, bn)
         bk, bn = mags.shape
+        # fold the fragment signs into the magnitudes (VPU broadcast-multiply)
         sgrid = jnp.broadcast_to(signs[:, None, :],
                                  (bk // m, m, bn)).reshape(bk, bn)
         acc_ref[...] += jnp.dot(x, mags * sgrid,
                                 preferred_element_type=jnp.float32)
+
+    if skip:
+        # the MAC is predicated on the tile occupancy bit (scalar-prefetched
+        # into SMEM, flattened row-major over the (M/bm, K/bk) tile grid),
+        # so dead input tiles never touch the MXU
+        pl.when(mask_ref[pl.program_id(0) * n_k_blocks + k_idx] != 0)(_mac)
+    else:
+        _mac()
 
     @pl.when(k_idx == n_k_blocks - 1)
     def _finish():
@@ -146,20 +132,20 @@ def polarized_matmul(
             f"bk={bk}); use ops.polarized_matmul for automatic padding")
 
     grid = (M // bm, N // bn, K // bk)
-    common_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-        pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-        pl.BlockSpec((bk // m, bn), lambda i, j, k: (k, j)),
-        pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+    in_specs = [
+        pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k)),
+        pl.BlockSpec((bk, bn), lambda i, j, k, *_: (k, j)),
+        pl.BlockSpec((bk // m, bn), lambda i, j, k, *_: (k, j)),
+        pl.BlockSpec((1, bn), lambda i, j, k, *_: (0, j)),
     ]
+    out_spec = pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j))
+    out_shape = jax.ShapeDtypeStruct((M, N), out_dtype)
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     if block_mask is None:
         return pl.pallas_call(
-            functools.partial(_kernel, m=m, n_k_blocks=grid[2]),
-            grid=grid,
-            in_specs=common_specs,
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            functools.partial(_kernel, m=m, n_k_blocks=grid[2], skip=False),
+            grid=grid, in_specs=in_specs, out_specs=out_spec,
+            out_shape=out_shape, scratch_shapes=scratch,
             interpret=interpret,
         )(x, mags, signs, scale)
 
@@ -169,17 +155,13 @@ def polarized_matmul(
             f"kernel grid: expected (M//bm, K//bk) = "
             f"{(M // bm, K // bk)} (kernels.sparsity.block_mask(x, "
             f"bm={bm}, bk={bk}))")
+    # the occupancy mask rides in as a scalar-prefetch operand: one int32
+    # per (i, k) tile in SMEM, readable as a scalar predicate
     return pl.pallas_call(
-        functools.partial(_kernel_skip, m=m, n_k_blocks=grid[2]),
-        grid=grid,
-        in_specs=common_specs + [
-            # one scalar occupancy bit per (i, k) tile, in SMEM so the
-            # predicate is readable without a VMEM round-trip
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, k),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        functools.partial(_kernel, m=m, n_k_blocks=grid[2], skip=True),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_spec, scratch_shapes=scratch),
+        out_shape=out_shape,
         interpret=interpret,
-    )(x, mags, signs, scale, block_mask.astype(jnp.int32))
+    )(block_mask.astype(jnp.int32).reshape(-1), x, mags, signs, scale)

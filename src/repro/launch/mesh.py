@@ -6,31 +6,35 @@ the 512-device host-platform flag.
 """
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import MeshConfig
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The assigned production meshes: 16x16 single-pod, 2x16x16 multi-pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(MeshConfig(pods=2 if multi_pod else 1))
 
 
 def make_mesh(cfg: MeshConfig):
-    """Arbitrary (pods, data, model) mesh — the elastic-scaling entry point."""
+    """Arbitrary (pods, data, model) mesh — the elastic-scaling entry point
+    and the one mesh constructor of the repo.
+
+    Axes are ``AxisType.Auto``: GSPMD propagates shardings from the
+    ``NamedSharding`` placements and ``constrain`` annotations.  (JAX's
+    default, explicit-sharding axes, would need an ``out_sharding`` on ops
+    such as the vocab-sharded embedding gather.)
+    """
+    shape, axes = (cfg.data, cfg.model), ("data", "model")
     if cfg.pods > 1:
-        return jax.make_mesh((cfg.pods, cfg.data, cfg.model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((cfg.data, cfg.model), ("data", "model"))
+        shape, axes = (cfg.pods,) + shape, ("pod",) + axes
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh():
     """Trivial mesh for tests/examples on one device."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh(MeshConfig(data=1, model=1))
 
 
 def parse_mesh_arg(arg: str) -> MeshConfig:

@@ -109,6 +109,7 @@ import time
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -265,6 +266,7 @@ def main() -> None:
     ap.add_argument("--fake-devices", type=int, default=None,
                     help="force N host-platform devices (CPU demo/testing)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.fake_devices:
         # must land before the first jax backend touch below

@@ -25,6 +25,7 @@ from repro.configs.base import MeshConfig, TrainConfig
 from repro.core import admm as admm_mod
 from repro.data.synthetic import LMStreamConfig, lm_batch
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.registry import build
 from repro.training import train_loop
@@ -54,6 +55,7 @@ def main() -> None:
     ap.add_argument("--mesh-model", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = build(cfg)

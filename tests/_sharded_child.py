@@ -10,7 +10,8 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-from repro.launch.mesh import force_host_device_count  # noqa: E402
+from repro.configs.base import MeshConfig  # noqa: E402
+from repro.launch.mesh import force_host_device_count, make_mesh  # noqa: E402
 
 # replace (not append) any inherited count flag; the jax backend has not
 # initialized yet, so this still takes effect
@@ -55,7 +56,7 @@ def check_parity():
     ref = ServingEngine(m, params, max_len=32, batch_slots=4, forms=True)
     want = {r.uid: r.tokens for r in ref.run(_requests())}
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     eng = ServingEngine(m, params, max_len=32, batch_slots=4, forms=True,
                         mesh=mesh)
     # compressed leaves co-shard along N over the model axis
@@ -79,7 +80,7 @@ def check_donation():
     consumes the old shards in place (no full-cache copy per block)."""
     m = _tiny_model()
     params = m.init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     eng = ServingEngine(m, params, max_len=32, batch_slots=4, forms=True,
                         mesh=mesh)
     eng.prefill_slot(0, np.array([5, 6], np.int32))
@@ -106,7 +107,7 @@ def check_fallback():
     ref = ServingEngine(m, params, max_len=32, batch_slots=2, forms=True)
     want = {r.uid: r.tokens for r in ref.run(_requests(2))}
 
-    mesh = jax.make_mesh((1, 16), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=1, model=16))
     eng = ServingEngine(m, params, max_len=32, batch_slots=2, forms=True,
                         mesh=mesh)
     wq = eng.params["blocks"]["attn"]["wq"]   # (L, 192, 192) compressed
@@ -138,7 +139,7 @@ def check_paged():
     want = {r.uid: r.tokens for r in dense.run(_requests(4, new=5))}
     assert dense.scheduler.max_concurrent == 2
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     # same budget: dense = 2 slots x 32 rows; pool = 8 pages x 8 rows
     eng = ServingEngine(m, params, max_len=32, batch_slots=4, forms=True,
                         mesh=mesh, page_size=8, num_pages=8)
@@ -169,7 +170,7 @@ def check_speculative():
                         forms=True)
     want = {r.uid: r.tokens for r in ref.run(_requests())}
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     eng = ServingEngine(m, params, max_len=32, batch_slots=4, page_size=8,
                         forms=True, mesh=mesh, speculate=True, draft_k=4,
                         draft_bits=4)
@@ -209,7 +210,7 @@ def check_restore():
     m = _tiny_model()
     params = m.init(jax.random.PRNGKey(0))
     comp, _ = compress_tree(params, FormsSpec(m=8))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     ctx = shd.ParallelContext.for_mesh(mesh)
     sh = shd.params_shardings(comp, ctx, fsdp=False)
     with tempfile.TemporaryDirectory() as d:
@@ -253,7 +254,7 @@ def check_mixed_precision():
     assert ref.compression_report.bits["blocks/attn/wq"] == 4
     want = {r.uid: r.tokens for r in ref.run(_requests())}
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     eng = ServingEngine(m, params, max_len=32, batch_slots=4, spec=spec,
                         plan=plan, mesh=mesh)
     wq = eng.params["blocks"]["attn"]["wq"]
@@ -306,7 +307,7 @@ def check_fleet():
                         forms=True)
     want = {r.uid: r.tokens for r in ref.run(reqs())}
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     eng = ServingEngine(m, params, max_len=32, batch_slots=2, page_size=4,
                         forms=True, mesh=mesh,
                         slo={"prefill_chunk": 4, "step_token_budget": 8})
@@ -320,6 +321,30 @@ def check_fleet():
     # chunked commits kept the pool donated and mesh-placed
     assert _spec_entries(eng.cache.pool["k"])[1] == "data"
     print("fleet ok:", want)
+
+
+def check_kernel_mesh():
+    """The Pallas kernel path itself on a data=2,model=2 mesh (interpret
+    mode here; compiled on a TPU, where Mosaic kernels need the per-device
+    shard_map of kernels/ops.py): paged FORMS decode is token-identical to
+    the single-device engine, with row-parallel weights placed K-sharded."""
+    from repro.forms import FormsSpec
+
+    m = _tiny_model()
+    params = m.init(jax.random.PRNGKey(0))
+    spec = FormsSpec(m=8, prefer_ref=False)
+    ref = ServingEngine(m, params, max_len=32, batch_slots=4, spec=spec,
+                        page_size=8)
+    want = {r.uid: r.tokens for r in ref.run(_requests())}
+
+    mesh = make_mesh(MeshConfig(data=2, model=2))
+    eng = ServingEngine(m, params, max_len=32, batch_slots=4, spec=spec,
+                        page_size=8, mesh=mesh)
+    down = eng.params["blocks"]["mlp"]["down"]
+    assert _spec_entries(down.mags)[-2] == "model", down.mags.sharding
+    got = {r.uid: r.tokens for r in eng.run(_requests())}
+    assert got == want, (got, want)
+    print("kernel_mesh ok:", want)
 
 
 def check_repair():
@@ -336,7 +361,7 @@ def check_repair():
                         page_size=8)
     want = {r.uid: r.tokens for r in ref.run(_requests())}
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     eng = ServingEngine(m, params, max_len=32, batch_slots=4, forms=True,
                         mesh=mesh, page_size=8,
                         health=HealthConfig(probe_every=1,

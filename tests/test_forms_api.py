@@ -7,6 +7,7 @@ decode directly on a compressed pytree, checkpointing with uint8 magnitudes
 on disk, and the removal of the PR-1 legacy shims.
 """
 import dataclasses
+import functools
 import os
 
 import jax
@@ -34,8 +35,14 @@ def _mixed_tree():
     }
 
 
+@functools.partial(jax.jit, static_argnames="spec")
 def _reference_projection(w2d, spec):
-    """The polarize->quantize projection compress_tree must invert exactly."""
+    """The polarize->quantize projection compress_tree must invert exactly.
+
+    Compiled like compress_tree's per-leaf conversion: XLA turns the
+    division by the level count into a multiply by its reciprocal, so an
+    op-by-op run of the same projection differs in the last bit of the
+    scale."""
     mat = pad_rows(w2d.astype(jnp.float32), spec.m)
     pol, _ = polmod.project_polarize(mat, spec.m, rule=spec.rule)
     return quantmod.project_quantize(pol, spec.quant)[: w2d.shape[0]]

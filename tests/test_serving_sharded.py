@@ -61,6 +61,13 @@ def test_speculative_decode_on_mesh_parity():
     assert "speculative ok" in out
 
 
+def test_pallas_kernel_on_mesh_parity():
+    """The Pallas polarized kernel (not the jnp oracle) under data=2,model=2:
+    token-identical to the single-device engine."""
+    out = _run_child("kernel_mesh", devices=4)
+    assert "kernel_mesh ok" in out
+
+
 def test_fleet_chunked_prefill_on_mesh_parity():
     """The SLO fleet scheduler's chunked prefill under data=2,model=4:
     token-identical to the single-device plain paged engine, page pool

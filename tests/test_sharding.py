@@ -5,8 +5,9 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.configs.base import MeshConfig
 from repro.distributed import sharding as shd
-from repro.launch.mesh import single_device_mesh
+from repro.launch.mesh import make_mesh, single_device_mesh
 
 
 def test_param_spec_rules():
@@ -76,7 +77,7 @@ def test_reshard_state_roundtrip():
 
 
 def test_parallel_context_resolution():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = single_device_mesh()
     ctx = shd.ParallelContext.for_mesh(mesh)
     assert ctx.resolve("batch") == "data"
     assert ctx.resolve("model") == "model"
@@ -114,7 +115,7 @@ def test_compressed_tree_shards_on_8_devices():
     validator passes."""
     from repro.forms import FormsSpec, compress_tree, validate_tree_sharding
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh(MeshConfig(data=2, model=4))
     ctx = shd.ParallelContext.for_mesh(mesh)
     params = {"blocks": {"attn": {"wq": jnp.ones((2, 64, 128)),
                                   "wo": jnp.ones((2, 128, 64))}}}

@@ -50,12 +50,19 @@ def _sparsify(x, m, frac, seed):
 # block-skip kernel: bit-identical to the dense kernel, same tiling
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("row_tiles", [1, 3])
 @pytest.mark.parametrize("frac", [0.0, 0.5, 1.0])
-def test_block_skip_bitwise_identical(frac):
-    M, K, N, m = 8, 64, 16, 4
+def test_block_skip_bitwise_identical(frac, row_tiles):
+    M, K, N, m = 8 * row_tiles, 64, 16, 4
     bm, bn, bk = 8, 16, 16
     x, mags, signs, scale = _operands(0, M, K, N, m)
     x = _sparsify(x, m, frac, seed=1)
+    if row_tiles > 1:
+        # a different dead-tile pattern in every row of tiles: the skip
+        # kernel reads the flattened mask at i * (K // bk) + k, and an entry
+        # read for the wrong (i, k) would skip a live tile
+        live = (np.arange(row_tiles)[:, None] + np.arange(K // bk)) % 3 != 0
+        x = x * jnp.asarray(np.repeat(np.repeat(live, bm, 0), bk, 1))
     dense = kernel_matmul(x, mags, signs, scale, m=m, bm=bm, bn=bn, bk=bk,
                           interpret=True)
     mask = S.block_mask(x, bm, bk)
