@@ -146,7 +146,7 @@ def polarized_matmul(
             functools.partial(_kernel, m=m, n_k_blocks=grid[2], skip=False),
             grid=grid, in_specs=in_specs, out_specs=out_spec,
             out_shape=out_shape, scratch_shapes=scratch,
-            interpret=interpret,
+            interpret=interpret, name="polarized_matmul",
         )(x, mags, signs, scale)
 
     if block_mask.shape != grid[:1] + grid[2:]:
@@ -163,5 +163,5 @@ def polarized_matmul(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_spec, scratch_shapes=scratch),
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="polarized_matmul",
     )(block_mask.astype(jnp.int32).reshape(-1), x, mags, signs, scale)

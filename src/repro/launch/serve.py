@@ -506,6 +506,12 @@ def main() -> None:
                      f"frag {ov['fragment_sparsity']:.2f} "
                      f"({ov['calls']} matmuls)")
     print("stats: " + ", ".join(parts))
+    # which programs compiled, per width: a width seen twice recompiled
+    compiles = sorted((k[len("runner.compiles."):], v)
+                      for k, v in stats["counters"].items()
+                      if k.startswith("runner.compiles."))
+    if compiles:
+        print("compiles: " + ", ".join(f"{k} {v}" for k, v in compiles))
     if "slo" in stats:
         s = stats["slo"]
         print(f"slo: ttft p50 {s['ttft_ms']['p50']:.1f}ms "

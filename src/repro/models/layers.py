@@ -467,8 +467,9 @@ def embed_lookup(embed: jax.Array, tokens: jax.Array, dtype=jnp.bfloat16) -> jax
 
 
 def lm_logits(x: jax.Array, head: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
-    if isinstance(head, FormsLinearParams) and head.mags.ndim == 2:
-        logits = forms_apply(head, x, tag="head").astype(dtype)
-    else:
-        logits = x @ head.astype(dtype)
-    return constrain(logits, "batch", None, "model")
+    with jax.named_scope("lm_head"):
+        if isinstance(head, FormsLinearParams) and head.mags.ndim == 2:
+            logits = forms_apply(head, x, tag="head").astype(dtype)
+        else:
+            logits = x @ head.astype(dtype)
+        return constrain(logits, "batch", None, "model")

@@ -53,23 +53,26 @@ def init(cfg: ModelConfig, key) -> Params:
 def _block_apply(cfg: ModelConfig, bp: Params, x: jax.Array,
                  positions: jax.Array, cache, cache_pos, dtype, q_chunk: int,
                  collect_kv: bool = False):
-    h, new_cache = L.attention_block(
-        bp["attn"], L.rmsnorm(x, bp["norm1"], cfg.norm_eps),
-        n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads, hd=cfg.hd(),
-        rope_theta=cfg.rope_theta, positions=positions,
-        window=cfg.sliding_window, q_chunk=q_chunk,
-        cache=cache, cache_pos=cache_pos, return_kv=collect_kv, dtype=dtype)
-    x = x + h
-    mlp_in = L.rmsnorm(x, bp["norm2"], cfg.norm_eps)
-    if cfg.act_sparsity > 0.0:
-        # fragment-structured sparsification of the MLP input: gives the
-        # zero-skipping matmul path (FormsSpec(zero_skip=...)) dead whole
-        # fragments to skip in the gate/up projections, aligned with
-        # act_fragment (DESIGN.md §6g)
-        mlp_in = L.sparsify_fragments(mlp_in, cfg.act_fragment,
-                                      cfg.act_sparsity)
-    x = x + L.swiglu(bp["mlp"], mlp_in, dtype, act=cfg.mlp_act,
-                     frag_drop=cfg.act_sparsity, frag_m=cfg.act_fragment)
+    with jax.named_scope("attention"):
+        h, new_cache = L.attention_block(
+            bp["attn"], L.rmsnorm(x, bp["norm1"], cfg.norm_eps),
+            n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads, hd=cfg.hd(),
+            rope_theta=cfg.rope_theta, positions=positions,
+            window=cfg.sliding_window, q_chunk=q_chunk,
+            cache=cache, cache_pos=cache_pos, return_kv=collect_kv,
+            dtype=dtype)
+        x = x + h
+    with jax.named_scope("mlp"):
+        mlp_in = L.rmsnorm(x, bp["norm2"], cfg.norm_eps)
+        if cfg.act_sparsity > 0.0:
+            # fragment-structured sparsification of the MLP input: gives the
+            # zero-skipping matmul path (FormsSpec(zero_skip=...)) dead
+            # whole fragments to skip in the gate/up projections, aligned
+            # with act_fragment (DESIGN.md §6g)
+            mlp_in = L.sparsify_fragments(mlp_in, cfg.act_fragment,
+                                          cfg.act_sparsity)
+        x = x + L.swiglu(bp["mlp"], mlp_in, dtype, act=cfg.mlp_act,
+                         frag_drop=cfg.act_sparsity, frag_m=cfg.act_fragment)
     return x, new_cache
 
 
@@ -149,7 +152,9 @@ def _prefill_core(cfg: ModelConfig, params: Params, tokens: jax.Array,
                                L.DEFAULT_Q_CHUNK, collect_kv=True)
         return out, kv
 
-    x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+    # the scan's own per-layer slices and stacked rows read as "layers"
+    with jax.named_scope("layers"):
+        x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     logits = L.lm_logits(x_last, head_matrix(cfg, params), dtype)
@@ -207,8 +212,11 @@ def _decode_core(cfg: ModelConfig, params: Params, tokens: jax.Array,
                                       positions, dtype, L.DEFAULT_Q_CHUNK)
         return out, new_cache
 
-    x, (k_tok, v_tok) = jax.lax.scan(body, x, (params["blocks"], k_cache,
-                                               v_cache))
+    # the scan's own per-layer slices (weights, K/V views) and stacked
+    # new-token rows read as "layers"
+    with jax.named_scope("layers"):
+        x, (k_tok, v_tok) = jax.lax.scan(body, x, (params["blocks"], k_cache,
+                                                   v_cache))
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.lm_logits(x, head_matrix(cfg, params), dtype)
     return logits, k_tok, v_tok

@@ -70,6 +70,7 @@ from repro.forms import (CompressReport, FormsSpec, compress_tree,
 from repro.kernels.sparsity import SparsityMeter
 from repro.models.registry import Model
 from repro.serving import kv_cache as KV
+from repro.serving import trace as T
 
 
 @dataclasses.dataclass
@@ -127,8 +128,11 @@ class ModelRunner:
                  ctx: Optional[ParallelContext] = None,
                  decode_block: int = 4, donate: bool = True,
                  rng_seed: int = 0,
-                 cache_shardings: Any = None):
+                 cache_shardings: Any = None,
+                 tracer: Optional[T.Tracer] = None):
         self.model = model
+        # spans (runner.prepare / dispatch / wait) and compile counts
+        self.tracer = tracer if tracer is not None else T.Tracer()
         self.params = params
         self.cache = cache
         self.paged = isinstance(cache, KV.PagedKVCache)
@@ -162,6 +166,11 @@ class ModelRunner:
                                **self._out_shardings_kw())
         self._prefill_fns: Dict[int, Any] = {}
         self._chunk_fns: Dict[int, Any] = {}
+        self._called: set = set()           # (program, width) called so far
+        # (program, width) -> its lowering, kept from its first call while
+        # the tracer is on, and its compiled HLO text once asked for
+        self._lowered: Dict[Tuple[str, int], Any] = {}
+        self._hlo: Dict[Tuple[str, int], str] = {}
 
     def _decode_impl(self, p, c, toks, pos, temps, key, step):
         """The shared decode-block scan: ``decode_block`` model steps with
@@ -263,21 +272,57 @@ class ModelRunner:
         ``pages`` is the int32 destination-page vector covering the bucket
         (scratch-0 entries skip prefix-shared pages)."""
         toks, n = self.padded_prompt(prompt)
-        self._key, sub = jax.random.split(self._key)
-        fn = self._get_prefill(toks.shape[1])
-        args = [self.params, jnp.asarray(toks), self.cache]
-        if self.paged:
-            if pages is None:
-                raise ValueError("paged prefill needs a destination-page "
-                                 "vector (pages=...)")
-            args.append(jnp.asarray(pages, jnp.int32))
-        args += [jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32),
-                 jnp.asarray(temperature, jnp.float32), sub]
+        if self.paged and pages is None:
+            raise ValueError("paged prefill needs a destination-page "
+                             "vector (pages=...)")
+        bucket = toks.shape[1]
+        fn = self._get_prefill(bucket)
+        with self.tracer.span("runner.prepare"):
+            self._key, sub = jax.random.split(self._key)
+            args = [self.params, jnp.asarray(toks), self.cache]
+            if self.paged:
+                args.append(jnp.asarray(pages, jnp.int32))
+            args += [jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32),
+                     jnp.asarray(temperature, jnp.float32), sub]
+        tok, self.cache = self._dispatch("prefill", bucket, fn, args)
+        with self.tracer.span("runner.wait"):
+            return int(tok)
+
+    def _dispatch(self, program: str, width: int, fn: Any, args: Any) -> Any:
+        """Call one jitted program (the ``runner.dispatch`` span), and
+        count a compilation of ``program`` at ``width`` in the counter
+        ``runner.compiles.<program>.<width>`` when this is the program's
+        first call or XLA compiled (or loaded from its cache) during it.
+
+        The first call of a program while the tracer is on keeps its
+        lowering (a lookup in JAX's caches once the program has run), from
+        which :meth:`hlo_texts` reads the compiled program."""
+        key = (program, width)
+        first = key not in self._called
+        self._called.add(key)
+        seen = T.backend_compiles()
         # parallel_context makes the models' logical-axis ``constrain``
-        # annotations live while a new bucket traces (no-op when ctx is None)
+        # annotations live while a new program traces (no-op when ctx is
+        # None)
         with parallel_context(self.ctx):
-            tok, self.cache = fn(*args)
-        return int(tok)
+            if self.tracer.on and key not in self._lowered:
+                self._lowered[key] = fn.lower(*args)
+            with self.tracer.span("runner.dispatch", program=program,
+                                  width=width):
+                out = fn(*args)
+        if first or T.backend_compiles() != seen:
+            self.tracer.count(f"runner.compiles.{program}.{width}")
+        return out
+
+    def hlo_texts(self) -> Dict[str, str]:
+        """The compiled HLO text of each program called while the tracer
+        was on, by ``"<program>.<width>"``.  A TPU profile names a device
+        operation by its HLO instruction alone; the instructions'
+        ``op_name`` metadata carries the named scopes."""
+        for key, low in self._lowered.items():
+            if key not in self._hlo:
+                self._hlo[key] = low.compile().as_text()
+        return {f"{p}.{w}": text for (p, w), text in self._hlo.items()}
 
     # ------------------------------------------------------------------
     # chunked (incremental) prefill — the fleet scheduler's admission path
@@ -338,17 +383,19 @@ class ModelRunner:
         if not self.paged:
             raise ValueError("chunked prefill needs the paged cache "
                              "(page_size=...)")
-        self._key, sub = jax.random.split(self._key)
-        fn = self._get_chunk(tokens.shape[1])
-        with parallel_context(self.ctx):
-            tok, self.cache = fn(
-                self.params, self.cache,
-                jnp.array(tokens, jnp.int32, copy=True),
-                jnp.array(positions, jnp.int32, copy=True),
-                jnp.array(block_tables, jnp.int32, copy=True),
-                jnp.array(cols, jnp.int32, copy=True),
-                jnp.array(temps, jnp.float32, copy=True), sub)
-        return np.asarray(tok)
+        width = tokens.shape[1]
+        fn = self._get_chunk(width)
+        with self.tracer.span("runner.prepare"):
+            self._key, sub = jax.random.split(self._key)
+            args = (self.params, self.cache,
+                    jnp.array(tokens, jnp.int32, copy=True),
+                    jnp.array(positions, jnp.int32, copy=True),
+                    jnp.array(block_tables, jnp.int32, copy=True),
+                    jnp.array(cols, jnp.int32, copy=True),
+                    jnp.array(temps, jnp.float32, copy=True), sub)
+        tok, self.cache = self._dispatch("chunk", width, fn, args)
+        with self.tracer.span("runner.wait"):
+            return np.asarray(tok)
 
     # ------------------------------------------------------------------
     # decode
@@ -367,18 +414,20 @@ class ModelRunner:
         right after is a read race (observed: decode steps seeing
         next-iteration positions).
         """
-        self._key, sub = jax.random.split(self._key)
-        args = [self.params, self.cache,
-                jnp.array(tokens, jnp.int32, copy=True),
-                jnp.array(positions, jnp.int32, copy=True)]
-        if self.paged:
-            if block_tables is None:
-                raise ValueError("paged decode needs block_tables")
-            args.append(jnp.array(block_tables, jnp.int32, copy=True))
-        args += [jnp.array(temps, jnp.float32, copy=True), sub]
-        with parallel_context(self.ctx):
-            toks_out, self.cache = self._decode(*args)
-        return np.asarray(toks_out)
+        if self.paged and block_tables is None:
+            raise ValueError("paged decode needs block_tables")
+        with self.tracer.span("runner.prepare"):
+            self._key, sub = jax.random.split(self._key)
+            args = [self.params, self.cache,
+                    jnp.array(tokens, jnp.int32, copy=True),
+                    jnp.array(positions, jnp.int32, copy=True)]
+            if self.paged:
+                args.append(jnp.array(block_tables, jnp.int32, copy=True))
+            args += [jnp.array(temps, jnp.float32, copy=True), sub]
+        toks_out, self.cache = self._dispatch("decode", self.decode_block,
+                                              self._decode, args)
+        with self.tracer.span("runner.wait"):
+            return np.asarray(toks_out)
 
     def decode_round(self, tokens: np.ndarray, positions: np.ndarray,
                      temps: np.ndarray,
@@ -425,6 +474,7 @@ class Scheduler:
                  health: Optional[Any] = None,
                  log_every: int = 0):
         self.runner = runner
+        self.tracer = runner.tracer      # sched.* and request.* spans, counters
         self.slots = slots
         self.max_len = max_len
         self.allocator = allocator
@@ -500,6 +550,10 @@ class Scheduler:
             [KV.SCRATCH_PAGE if j < len(shared) else pages[j]
              for j in range(n_bucket_pages)], np.int32)
 
+    def _probe_health(self) -> None:
+        with self.tracer.span("health.probe", round=self.rounds):
+            self.health.tick(self.runner, self.rounds)
+
     def _release_slot(self, slot: int) -> None:
         if not self.paged:
             return
@@ -515,12 +569,15 @@ class Scheduler:
 
     def run(self, requests: List[Request]) -> List[Result]:
         """Serve a list of requests with continuous batching over slots."""
+        tr = self.tracer
         queue = list(requests)
         active: List[Optional[Tuple[Request, Result]]] = [None] * self.slots
         done: List[Result] = []
         cur = np.zeros(self.slots, np.int32)        # current token per slot
         slot_pos = np.zeros(self.slots, np.int32)   # next cache write position
         temps = np.zeros(self.slots, np.float32)
+        since = [0.0] * self.slots       # start of each slot's decode span
+        t_start = time.perf_counter()
 
         def admit(slot: int) -> None:
             """Admit queued requests into ``slot`` until one survives its
@@ -551,17 +608,21 @@ class Scheduler:
                         return
                 queue.pop(0)
                 res = Result(uid=req.uid, tokens=[])
-                t0 = time.perf_counter()
-                first = self.runner.prefill_slot(slot, prompt,
-                                                 req.temperature, pages=pages)
-                res.prefill_ms = (time.perf_counter() - t0) * 1e3
-                res.tokens.append(first)
                 n_prompt = int(prompt.shape[0])
+                first, sp = self._bulk_prefill(slot, prompt, req.temperature,
+                                               pages)
+                res.prefill_ms = sp.seconds * 1e3
+                tr.record("request.queue", t_start, sp.start, uid=req.uid)
+                tr.record("request.prefill", sp.start, sp.end, uid=req.uid)
+                res.tokens.append(first)
                 if (len(res.tokens) >= req.max_new_tokens
                         or n_prompt >= self.max_len - 1):
                     self._release_slot(slot)
                     done.append(res)
+                    tr.record("request.decode", sp.end, sp.end, uid=req.uid,
+                              tokens=len(res.tokens), preemptions=0)
                     continue
+                since[slot] = sp.end
                 if self.paged and self.prefix is not None:
                     self.prefix.register(prompt, self.slot_pages[slot])
                 cur[slot] = first
@@ -575,7 +636,10 @@ class Scheduler:
                 return
 
         def finish(slot: int) -> None:
-            done.append(active[slot][1])
+            res = active[slot][1]
+            done.append(res)
+            tr.record("request.decode", since[slot], time.perf_counter(),
+                      uid=res.uid, tokens=len(res.tokens), preemptions=0)
             active[slot] = None
             temps[slot] = 0.0
             self._release_slot(slot)
@@ -600,24 +664,52 @@ class Scheduler:
         # sat idle are repaired before they can poison KV pages, so a
         # repaired run is greedy-identical to a clean one end to end
         if self.health is not None:
-            self.health.tick(self.runner, self.rounds)
-        admit_idle()
+            self._probe_health()
+        with tr.span("sched.admit"):
+            admit_idle()
 
         while any(a is not None for a in active):
             # snapshot the attribution denominator BEFORE the loop body
             # mutates ``active`` (finished slots must still pay their share
             # of the round they took part in)
             n_active = sum(a is not None for a in active)
-            t0 = time.perf_counter()
-            # a round yields a VARIABLE number of tokens per slot: a fixed
-            # decode_block on the plain runner, 1 + accepted drafts on the
-            # speculative runner — counts[s] is the only source of truth
+            with tr.span("sched.round", round=self.rounds,
+                         decoding=n_active, prefilling=0,
+                         queued=len(queue)):
+                self._plain_round(active, cur, slot_pos, temps, n_active,
+                                  finish)
+                # periodic health pass between rounds: in-flight requests
+                # keep their slots, pages and positions across a repair —
+                # only the runner's params binding changes (same
+                # shapes/shardings, no retrace), so nothing is dropped
+                if (self.health is not None
+                        and self.health.config.probe_every
+                        and self.rounds % self.health.config.probe_every
+                        == 0):
+                    self._probe_health()
+                self._log_round(sum(a is not None for a in active))
+                with tr.span("sched.admit"):
+                    admit_idle()
+        return done
+
+    def _plain_round(self, active, cur, slot_pos, temps, n_active: int,
+                     finish) -> None:
+        """One decode round of :meth:`run` and its token bookkeeping."""
+        tr = self.tracer
+        act = [a is not None for a in active]
+        # a round yields a VARIABLE number of tokens per slot: a fixed
+        # decode_block on the plain runner, 1 + accepted drafts on the
+        # speculative runner — counts[s] is the only source of truth
+        with tr.timed("runner.decode", rows=n_active,
+                      steps=self.runner.decode_block) as sp:
             out, counts = self.runner.decode_round(
                 cur, slot_pos, temps,
                 block_tables=self.block_tables if self.paged else None,
-                active=[a is not None for a in active])
-            dt = (time.perf_counter() - t0) * 1e3
-            self.rounds += 1
+                active=act)
+        dt = sp.seconds * 1e3
+        self.rounds += 1
+        kept = 0
+        with tr.span("sched.emit"):
             for s in range(self.slots):
                 a = active[s]
                 if a is None:
@@ -630,6 +722,7 @@ class Scheduler:
                              self.max_len - 1 - int(slot_pos[s]))
                 take = min(int(counts[s]), budget)
                 res.tokens.extend(int(t) for t in out[:take, s])
+                kept += take
                 if take >= budget:
                     finish(s)      # may re-admit into this slot
                 else:
@@ -638,16 +731,36 @@ class Scheduler:
                     # counts[s]; rows beyond it are dead by the masks)
                     cur[s] = out[counts[s] - 1, s]
                     slot_pos[s] += int(counts[s])
-            # periodic health pass between rounds: in-flight requests keep
-            # their slots, pages and positions across a repair — only the
-            # runner's params binding changes (same shapes/shardings, no
-            # retrace), so nothing is dropped
-            if (self.health is not None and self.health.config.probe_every
-                    and self.rounds % self.health.config.probe_every == 0):
-                self.health.tick(self.runner, self.rounds)
-            self._log_round(sum(a is not None for a in active))
-            admit_idle()
-        return done
+        self._count_decode(out.size, kept)
+
+    def _count_decode(self, rows: int, kept: int) -> None:
+        """Decode counters: a round, its (steps x slots) rows dispatched
+        and the tokens the requests kept."""
+        tr = self.tracer
+        tr.count("decode.rounds")
+        tr.count("decode.rows", rows)
+        tr.count("decode.tokens", kept)
+
+    def _bulk_prefill(self, slot: int, prompt: np.ndarray,
+                      temperature: float, pages: Optional[np.ndarray]
+                      ) -> Tuple[int, T.Span]:
+        """One whole-prompt prefill call, timed by its ``runner.prefill``
+        span and counted; returns the first token and the span."""
+        n = int(prompt.shape[0])
+        width = self.runner.bucket_for(n)
+        with self.tracer.timed("runner.prefill", rows=1, width=width) as sp:
+            first = self.runner.prefill_slot(slot, prompt, temperature,
+                                             pages=pages)
+        self._count_prefill(n, width)
+        return first, sp
+
+    def _count_prefill(self, tokens: int, rows: int) -> None:
+        """Prefill counters: a call, the prompt tokens it computed and the
+        (rows x width) positions it dispatched."""
+        tr = self.tracer
+        tr.count("prefill.calls")
+        tr.count("prefill.tokens", tokens)
+        tr.count("prefill.rows", rows)
 
     def _log_round(self, n_active: int) -> None:
         """The serve CLI's periodic stat line (``log_every`` rounds)."""
@@ -698,7 +811,12 @@ class ServingEngine:
     from the build-time reference copy — fault-tolerant serving that never
     drops in-flight requests.  ``engine.inject_faults(FaultModel(...))``
     corrupts the live params for experiments; ``stats()["health"]`` is the
-    scoreboard."""
+    scoreboard.
+
+    ``engine.tracer`` (serving/trace.py) records the serving loop's spans
+    while an in-process profiler session runs, and always after
+    ``engine.tracer.enable()``.  Its counters are always
+    on; ``stats()["counters"]`` and ``stats()["trace"]`` report them."""
 
     def __init__(self, model: Model, params: Any, *, max_len: int = 512,
                  batch_slots: int = 8, forms: bool = False,
@@ -769,6 +887,7 @@ class ServingEngine:
         # families (and page_size=0) fall back to the plain engine, like the
         # paged-cache fallback itself
         self.speculative = bool(speculate) and self.paged
+        tracer = T.Tracer()
         allocator = prefix = None
         if self.paged:
             per_slot = KV.pages_for(max_len, self.page_size)
@@ -837,13 +956,14 @@ class ServingEngine:
                 draft_cache_shardings=self.draft_cache_shardings,
                 max_len=max_len, spec=self.spec, ctx=self.ctx,
                 decode_block=decode_block, donate=donate, rng_seed=rng_seed,
-                cache_shardings=self.cache_shardings)
+                cache_shardings=self.cache_shardings, tracer=tracer)
         else:
             self.runner = ModelRunner(model, params, cache, max_len=max_len,
                                       spec=self.spec,
                                       ctx=self.ctx, decode_block=decode_block,
                                       donate=donate, rng_seed=rng_seed,
-                                      cache_shardings=self.cache_shardings)
+                                      cache_shardings=self.cache_shardings,
+                                      tracer=tracer)
         # install the sparsity meter before the first decode trace (the
         # debug callbacks bake into the traced fn); off by default because
         # each forms matmul then costs one host round-trip per decode step
@@ -892,6 +1012,10 @@ class ServingEngine:
         return self.runner.decode_block
 
     @property
+    def tracer(self) -> T.Tracer:
+        return self.runner.tracer
+
+    @property
     def page_allocator(self) -> Optional[KV.PageAllocator]:
         return self.scheduler.allocator
 
@@ -912,7 +1036,9 @@ class ServingEngine:
         (free/used/shared/high-water), prefix-cache hits, with speculation
         on acceptance-rate/tokens-per-round, and with the fleet scheduler
         the ``"slo"`` block (TTFT/inter-token percentiles, preemption and
-        deadline-miss counts, queue depths per class).
+        deadline-miss counts, queue depths per class); always the tracer's
+        ``"counters"``, and its ``"trace"`` (spans, dropped, and the
+        runner's ``hlo_texts()``) once it is on or has recorded a span.
 
         The returned dict is a DEEP-COPIED snapshot: the health/sparsity/
         SLO sub-dicts are mutated by the serving loop, and a caller polling
@@ -935,7 +1061,11 @@ class ServingEngine:
             out["sparsity"] = self.sparsity_meter.summary()
         if hasattr(self.scheduler, "slo_stats"):
             out["slo"] = self.scheduler.slo_stats()
-        return copy.deepcopy(out)
+        out = copy.deepcopy(out)
+        out.update(self.tracer.stats())     # fresh objects, no copy needed
+        if "trace" in out:
+            out["trace"]["hlo"] = self.runner.hlo_texts()
+        return out
 
     def inject_faults(self, fault: Any, paths: Optional[List[str]] = None
                       ) -> Any:
@@ -978,7 +1108,8 @@ def _sample_on_device(logits: jax.Array, temps: jax.Array,
     logits: (B, V) f32; temps: (B,) — rows with temp <= 0 take the argmax,
     others sample from softmax(logits / temp) via ``jax.random.categorical``.
     """
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    with jax.named_scope("sampling"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+        sampled = jax.random.categorical(key, scaled).astype(jnp.int32)
+        return jnp.where(temps > 0.0, sampled, greedy)

@@ -88,10 +88,11 @@ def gather_views(cache: PagedKVCache, block_tables: jax.Array
     """
     b, n = block_tables.shape
     out = {}
-    for name, pool in cache.pool.items():
-        v = pool[:, block_tables]               # (L, B, n, ps, ...)
-        out[name] = v.reshape(v.shape[0], b, n * cache.page_size,
-                              *v.shape[4:])
+    with jax.named_scope("kv_gather"):
+        for name, pool in cache.pool.items():
+            v = pool[:, block_tables]               # (L, B, n, ps, ...)
+            out[name] = v.reshape(v.shape[0], b, n * cache.page_size,
+                                  *v.shape[4:])
     return out
 
 
@@ -130,13 +131,14 @@ def commit_tokens(cache: PagedKVCache, toks: Dict[str, jax.Array],
     (:func:`resolve_pages`).
     """
     t = next(iter(toks.values())).shape[2]
-    pos = jnp.asarray(pos, jnp.int32)
-    grid = (pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
-            if pos.ndim == 1 else pos)
-    page, off = resolve_pages(block_tables, grid, cache.page_size)
-    pool = {name: cache.pool[name].at[:, page, off].set(
-        tok.astype(cache.pool[name].dtype))
-        for name, tok in toks.items()}
+    with jax.named_scope("kv_commit"):
+        pos = jnp.asarray(pos, jnp.int32)
+        grid = (pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+                if pos.ndim == 1 else pos)
+        page, off = resolve_pages(block_tables, grid, cache.page_size)
+        pool = {name: cache.pool[name].at[:, page, off].set(
+            tok.astype(cache.pool[name].dtype))
+            for name, tok in toks.items()}
     return dataclasses.replace(cache, pool=pool)
 
 
@@ -167,16 +169,18 @@ def rollback_tokens(cache: PagedKVCache, block_tables: jax.Array,
     redirect, rows of other slots) are untouched: the zero-write for a
     kept position is redirected to the scratch page.
     """
-    pos = jnp.asarray(pos, jnp.int32)
-    keep = jnp.asarray(keep, jnp.int32)
-    offs = jnp.arange(t, dtype=jnp.int32)[None, :]
-    page, off = resolve_pages(block_tables, pos[:, None] + offs,
-                              cache.page_size, select=offs >= keep[:, None])
     pool = {}
-    for name, arr in cache.pool.items():
-        zeros = jnp.zeros(arr.shape[:1] + page.shape + arr.shape[3:],
-                          arr.dtype)
-        pool[name] = arr.at[:, page, off].set(zeros)
+    with jax.named_scope("kv_commit"):
+        pos = jnp.asarray(pos, jnp.int32)
+        keep = jnp.asarray(keep, jnp.int32)
+        offs = jnp.arange(t, dtype=jnp.int32)[None, :]
+        page, off = resolve_pages(block_tables, pos[:, None] + offs,
+                                  cache.page_size,
+                                  select=offs >= keep[:, None])
+        for name, arr in cache.pool.items():
+            zeros = jnp.zeros(arr.shape[:1] + page.shape + arr.shape[3:],
+                              arr.dtype)
+            pool[name] = arr.at[:, page, off].set(zeros)
     return dataclasses.replace(cache, pool=pool)
 
 
@@ -195,16 +199,17 @@ def commit_pages(cache: PagedKVCache, leaves: Dict[str, jax.Array],
     """
     ps = cache.page_size
     pool = dict(cache.pool)
-    for name, arr in leaves.items():
-        l, _, s = arr.shape[:3]
-        pad = (-s) % ps
-        if pad:
-            arr = jnp.pad(arr, [(0, 0), (0, 0), (0, pad)]
-                          + [(0, 0)] * (arr.ndim - 3))
-        n = (s + pad) // ps
-        tiles = arr.reshape(l, n, ps, *arr.shape[3:])
-        pool[name] = pool[name].at[:, pages].set(
-            tiles.astype(pool[name].dtype))
+    with jax.named_scope("kv_commit"):
+        for name, arr in leaves.items():
+            l, _, s = arr.shape[:3]
+            pad = (-s) % ps
+            if pad:
+                arr = jnp.pad(arr, [(0, 0), (0, 0), (0, pad)]
+                              + [(0, 0)] * (arr.ndim - 3))
+            n = (s + pad) // ps
+            tiles = arr.reshape(l, n, ps, *arr.shape[3:])
+            pool[name] = pool[name].at[:, pages].set(
+                tiles.astype(pool[name].dtype))
     return dataclasses.replace(cache, pool=pool)
 
 

@@ -124,6 +124,7 @@ class _Entry:
     deadline: Optional[float]     # run-relative absolute deadline
     ttft_done: bool = False
     preempted: int = 0
+    since: float = 0.0            # perf_counter start of its current span
 
     def order_key(self):
         """EDF within priority; FIFO breaks deadline ties."""
@@ -203,8 +204,9 @@ class FleetScheduler(Scheduler):
         self.resumes = 0
         self.deadline_misses = 0
         self.completed = 0
-        self.chunk_calls = 0
-        self.chunk_tokens = 0
+        # chunked_prefill reads the prefill counters from here on
+        self._prefill_base = {k: self.tracer.counters.get(k, 0)
+                              for k in ("prefill.calls", "prefill.tokens")}
         w = self.cfg.window
         self._ttft = {p: _Window(w) for p in PRIORITIES}
         self._itl = {p: _Window(w) for p in PRIORITIES}
@@ -239,8 +241,11 @@ class FleetScheduler(Scheduler):
     # ------------------------------------------------------------------
 
     def run(self, requests: List[Request]) -> List[Result]:
+        tr = self.tracer
         self._t0 = time.perf_counter()
         queue: List[_Entry] = [self._make_entry(r) for r in requests]
+        for e in queue:
+            e.since = self._t0 + e.arrival
         runs: List[Optional[_SlotRun]] = [None] * self.slots
         done: List[Result] = []
         cur = np.zeros(self.slots, np.int32)
@@ -250,7 +255,7 @@ class FleetScheduler(Scheduler):
                      slot_pos=slot_pos, temps=temps)
 
         if self.health is not None:
-            self.health.tick(self.runner, self.rounds)
+            self._probe_health()
 
         while queue or any(r is not None for r in runs):
             now = self._now()
@@ -260,22 +265,32 @@ class FleetScheduler(Scheduler):
                 # the next arrival instead of spinning
                 time.sleep(max(0.0, min(e.arrival for e in queue) - now))
                 continue
-            self._admit(state)
-            self._sample_queue_depth(queue)
-            budget = self.cfg.step_token_budget or 1 << 30
-            per_slot = (self.runner.k_max + 1
-                        if hasattr(self.runner, "k_max")
-                        else self.runner.decode_block)
-            n_dec = sum(1 for r in runs
-                        if r is not None and r.phase == "decode")
-            self._prefill_round(state, max(0, budget - n_dec * per_slot))
-            self._decode_round(state)
-            self.rounds += 1
-            if (self.health is not None and self.health.config.probe_every
-                    and self.rounds % self.health.config.probe_every == 0):
-                self.health.tick(self.runner, self.rounds)
-            self._log_round(sum(r is not None for r in runs))
+            with tr.span("sched.round", round=self.rounds,
+                         decoding=self._in_phase(runs, "decode"),
+                         prefilling=self._in_phase(runs, "prefill"),
+                         queued=len(queue)):
+                with tr.span("sched.admit"):
+                    self._admit(state)
+                self._sample_queue_depth(queue)
+                budget = self.cfg.step_token_budget or 1 << 30
+                per_slot = (self.runner.k_max + 1
+                            if hasattr(self.runner, "k_max")
+                            else self.runner.decode_block)
+                n_dec = self._in_phase(runs, "decode")
+                self._prefill_round(state, max(0, budget - n_dec * per_slot))
+                self._decode_round(state)
+                self.rounds += 1
+                if (self.health is not None
+                        and self.health.config.probe_every
+                        and self.rounds % self.health.config.probe_every
+                        == 0):
+                    self._probe_health()
+                self._log_round(sum(r is not None for r in runs))
         return done
+
+    @staticmethod
+    def _in_phase(runs: List[Optional[_SlotRun]], phase: str) -> int:
+        return sum(1 for r in runs if r is not None and r.phase == phase)
 
     def _now(self) -> float:
         return time.perf_counter() - self._t0
@@ -344,6 +359,10 @@ class FleetScheduler(Scheduler):
         entry's Result, entry requeued for EDF re-admission."""
         runs, temps = state["runs"], state["temps"]
         st = runs[slot]
+        now = time.perf_counter()
+        self.tracer.record(f"request.{st.phase}", st.entry.since, now,
+                           uid=st.entry.req.uid, preempted=1)
+        st.entry.since = now
         st.entry.preempted += 1
         self.preemptions += 1
         self._class[PRIORITIES[st.entry.prio]]["preemptions"] += 1
@@ -377,6 +396,7 @@ class FleetScheduler(Scheduler):
             rows=min(n + max_new, self.max_len))
         if pages is None:
             return False
+        self._admitted(entry)
         runs[slot] = _SlotRun(entry=entry, prompt=prompt, n_prompt=n,
                               filled=self.last_shared * self.runner.page_size,
                               phase="prefill", last_emit=self._now())
@@ -393,18 +413,26 @@ class FleetScheduler(Scheduler):
         pages = self._reserve_pages(entry.req.uid, slot, prompt, max_new)
         if pages is None:
             return False
-        t0 = time.perf_counter()
-        first = self.runner.prefill_slot(slot, prompt, entry.req.temperature,
-                                         pages=pages)
-        entry.res.prefill_ms += (time.perf_counter() - t0) * 1e3
-        runs[slot] = _SlotRun(entry=entry, prompt=prompt,
-                              n_prompt=int(prompt.shape[0]),
-                              filled=int(prompt.shape[0]), phase="prefill",
-                              last_emit=self._now())
+        self._admitted(entry)
+        n = int(prompt.shape[0])
+        first, sp = self._bulk_prefill(slot, prompt, entry.req.temperature,
+                                       pages)
+        entry.res.prefill_ms += sp.seconds * 1e3
+        runs[slot] = _SlotRun(entry=entry, prompt=prompt, n_prompt=n,
+                              filled=n, phase="prefill",
+                              last_emit=sp.end - self._t0)
         self.max_concurrent = max(self.max_concurrent,
                                   sum(r is not None for r in runs))
-        self._first_token(state, slot, first)
+        with self.tracer.span("sched.emit"):
+            self._first_token(state, slot, first, sp.end)
         return True
+
+    def _admitted(self, entry: _Entry) -> None:
+        """The ``request.queue`` span of an entry that just won a slot."""
+        now = time.perf_counter()
+        self.tracer.record("request.queue", entry.since, now,
+                           uid=entry.req.uid)
+        entry.since = now
 
     # ------------------------------------------------------------------
     # chunked prefill rounds
@@ -435,7 +463,6 @@ class FleetScheduler(Scheduler):
             left -= take
         if not grants:
             return
-        t0 = time.perf_counter()
         width = self.runner.chunk_width(max(grants.values()))
         toks = np.zeros((self.slots, width), np.int32)
         pos = np.zeros(self.slots, np.int32)
@@ -449,27 +476,32 @@ class FleetScheduler(Scheduler):
             cols[s] = take - 1
             temps_c[s] = st.entry.req.temperature
             tables[s] = self.block_tables[s]
-        tok = self.runner.prefill_chunk(toks, pos, tables, cols, temps_c)
-        dt = (time.perf_counter() - t0) * 1e3
-        self.chunk_calls += 1
-        self.chunk_tokens += sum(grants.values())
-        for s, take in grants.items():
-            st = runs[s]
-            st.filled += take
-            st.entry.res.prefill_ms += dt / len(grants)
-            if st.filled >= st.n_prompt:
-                self._first_token(state, s, int(tok[s]))
+        tr = self.tracer
+        with tr.timed("runner.prefill", rows=len(grants), width=width) as sp:
+            tok = self.runner.prefill_chunk(toks, pos, tables, cols, temps_c)
+        dt = sp.seconds * 1e3
+        self._count_prefill(sum(grants.values()), self.slots * width)
+        with tr.span("sched.emit"):
+            for s, take in grants.items():
+                st = runs[s]
+                st.filled += take
+                st.entry.res.prefill_ms += dt / len(grants)
+                if st.filled >= st.n_prompt:
+                    self._first_token(state, s, int(tok[s]), sp.end)
 
-    def _first_token(self, state: Dict[str, Any], slot: int,
-                     tok: int) -> None:
-        """Prefill completed for ``slot``: record TTFT, register the
+    def _first_token(self, state: Dict[str, Any], slot: int, tok: int,
+                     t: float) -> None:
+        """Prefill completed for ``slot`` at ``t`` (perf_counter, the end
+        of the call that produced ``tok``): record TTFT, register the
         prefix, emit the first generated token, and either transition to
         decode or finish outright (budget/window exhausted)."""
         runs, cur = state["runs"], state["cur"]
         slot_pos, temps = state["slot_pos"], state["temps"]
         st = runs[slot]
         e = st.entry
-        now = self._now()
+        now = t - self._t0
+        self.tracer.record("request.prefill", e.since, t, uid=e.req.uid)
+        e.since = t
         e.res.tokens.append(tok)
         if not e.ttft_done:
             e.ttft_done = True
@@ -504,34 +536,43 @@ class FleetScheduler(Scheduler):
         mask = np.zeros(self.slots, bool)
         mask[decoding] = True
         tables = np.where(mask[:, None], self.block_tables, 0)
-        t0 = time.perf_counter()
-        out, counts = self.runner.decode_round(
-            cur, slot_pos, temps, block_tables=tables,
-            active=list(mask))
-        dt = (time.perf_counter() - t0) * 1e3
-        now = self._now()
-        for s in decoding:
-            st = runs[s]
-            e = st.entry
-            e.res.decode_ms += dt / len(decoding)
-            budget = min(e.req.max_new_tokens - len(e.res.tokens),
-                         self.max_len - 1 - int(slot_pos[s]))
-            take = min(int(counts[s]), budget)
-            e.res.tokens.extend(int(t) for t in out[:take, s])
-            if take > 0:
-                self._itl[PRIORITIES[e.prio]].add(
-                    (now - st.last_emit) * 1e3 / take, n=take)
-                st.last_emit = now
-            if take >= budget:
-                self._finish(state, s)
-            else:
-                cur[s] = out[counts[s] - 1, s]
-                slot_pos[s] += int(counts[s])
+        tr = self.tracer
+        with tr.timed("runner.decode", rows=len(decoding),
+                      steps=self.runner.decode_block) as sp:
+            out, counts = self.runner.decode_round(
+                cur, slot_pos, temps, block_tables=tables,
+                active=list(mask))
+        dt = sp.seconds * 1e3
+        now = sp.end - self._t0
+        kept = 0
+        with tr.span("sched.emit"):
+            for s in decoding:
+                st = runs[s]
+                e = st.entry
+                e.res.decode_ms += dt / len(decoding)
+                budget = min(e.req.max_new_tokens - len(e.res.tokens),
+                             self.max_len - 1 - int(slot_pos[s]))
+                take = min(int(counts[s]), budget)
+                e.res.tokens.extend(int(t) for t in out[:take, s])
+                kept += take
+                if take > 0:
+                    self._itl[PRIORITIES[e.prio]].add(
+                        (now - st.last_emit) * 1e3 / take, n=take)
+                    st.last_emit = now
+                if take >= budget:
+                    self._finish(state, s)
+                else:
+                    cur[s] = out[counts[s] - 1, s]
+                    slot_pos[s] += int(counts[s])
+        self._count_decode(out.size, kept)
 
     def _finish(self, state: Dict[str, Any], slot: int) -> None:
         runs, temps = state["runs"], state["temps"]
         st = runs[slot]
         e = st.entry
+        self.tracer.record("request.decode", e.since, time.perf_counter(),
+                           uid=e.req.uid, tokens=len(e.res.tokens),
+                           preemptions=e.preempted)
         self._release_slot(slot)
         runs[slot] = None
         temps[slot] = 0.0
@@ -565,8 +606,7 @@ class FleetScheduler(Scheduler):
             "preemptions": self.preemptions,
             "resumes": self.resumes,
             "deadline_misses": self.deadline_misses,
-            "chunked_prefill": {"calls": self.chunk_calls,
-                                "tokens": self.chunk_tokens},
+            "chunked_prefill": self._chunked_prefill(),
             "window_dropped": sum(w.dropped for w in
                                   list(self._ttft.values())
                                   + list(self._itl.values())),
@@ -581,13 +621,23 @@ class FleetScheduler(Scheduler):
             }
         return out
 
+    def _chunked_prefill(self) -> Dict[str, int]:
+        """Chunked-prefill calls and prompt tokens since the last
+        :meth:`reset_slo_stats` (none with whole-prompt admission)."""
+        if not self.chunk:
+            return {"calls": 0, "tokens": 0}
+        c, base = self.tracer.counters, self._prefill_base
+        return {"calls": c.get("prefill.calls", 0) - base["prefill.calls"],
+                "tokens": c.get("prefill.tokens", 0) - base["prefill.tokens"]}
+
     def _log_round(self, n_active: int) -> None:
         if not self.log_every or self.rounds % self.log_every:
             return
         super()._log_round(n_active)
         depths = ", ".join(f"{p} q={self._queue_depth[p]}"
                            for p in PRIORITIES)
+        chunks = self._chunked_prefill()
         print(f"[serve]   slo: {depths}, preempt {self.preemptions}, "
               f"miss {self.deadline_misses}, "
-              f"chunks {self.chunk_calls}/{self.chunk_tokens}tok",
+              f"chunks {chunks['calls']}/{chunks['tokens']}tok",
               flush=True)

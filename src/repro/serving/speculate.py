@@ -298,7 +298,6 @@ def _accept(logits_t: jax.Array, draft_lg: jax.Array, drafts: jax.Array,
 
 # imported late to avoid a module cycle (engine imports this module from
 # inside ServingEngine.__init__)
-from repro.distributed.sharding import parallel_context  # noqa: E402
 from repro.serving.engine import ModelRunner, _sample_on_device  # noqa: E402
 
 
@@ -447,18 +446,21 @@ class SpeculativeRunner(ModelRunner):
             np.int32)
         k_round = max((int(k_eligible[s]) for s in range(b) if act[s]),
                       default=self.k_max)
-        self._key, sub = jax.random.split(self._key)
-        args = (self.params, self.cache, self.draft_params, self.draft_cache,
-                jnp.array(tokens, jnp.int32, copy=True),
-                jnp.array(positions, jnp.int32, copy=True),
-                jnp.array(block_tables, jnp.int32, copy=True),
-                jnp.array(k_eligible, jnp.int32, copy=True),
-                jnp.array(temps, jnp.float32, copy=True), sub)
-        with parallel_context(self.ctx):
-            out, n_emit, self.cache, self.draft_cache = \
-                self._get_spec_step(k_round)(*args)
-        out = np.asarray(out)
-        counts = np.asarray(n_emit, dtype=np.int64).astype(np.int32)
+        fn = self._get_spec_step(k_round)
+        with self.tracer.span("runner.prepare"):
+            self._key, sub = jax.random.split(self._key)
+            args = (self.params, self.cache, self.draft_params,
+                    self.draft_cache,
+                    jnp.array(tokens, jnp.int32, copy=True),
+                    jnp.array(positions, jnp.int32, copy=True),
+                    jnp.array(block_tables, jnp.int32, copy=True),
+                    jnp.array(k_eligible, jnp.int32, copy=True),
+                    jnp.array(temps, jnp.float32, copy=True), sub)
+        out, n_emit, self.cache, self.draft_cache = self._dispatch(
+            "speculate", k_round, fn, args)
+        with self.tracer.span("runner.wait"):
+            out = np.asarray(out)
+            counts = np.asarray(n_emit, dtype=np.int64).astype(np.int32)
         self.rounds += 1
         cfg = self.spec_cfg
         for s in range(b):
@@ -491,13 +493,13 @@ class SpeculativeRunner(ModelRunner):
         prefix-shared pages in both pools identically)."""
         tok = super().prefill_slot(slot, prompt, temperature, pages=pages)
         toks, n = self.padded_prompt(prompt)
-        fn = self._get_draft_prefill(toks.shape[1])
-        with parallel_context(self.ctx):
-            self.draft_cache = fn(self.draft_params, jnp.asarray(toks),
-                                  self.draft_cache,
-                                  jnp.asarray(pages, jnp.int32),
-                                  jnp.asarray(slot, jnp.int32),
-                                  jnp.asarray(n, jnp.int32))
+        bucket = toks.shape[1]
+        fn = self._get_draft_prefill(bucket)
+        with self.tracer.span("runner.prepare"):
+            args = (self.draft_params, jnp.asarray(toks), self.draft_cache,
+                    jnp.asarray(pages, jnp.int32),
+                    jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32))
+        self.draft_cache = self._dispatch("draft_prefill", bucket, fn, args)
         return tok
 
     def prefill_chunk(self, tokens: np.ndarray, positions: np.ndarray,
@@ -510,13 +512,14 @@ class SpeculativeRunner(ModelRunner):
         draft pool position-synced — exactly the bulk-admission state."""
         tok = super().prefill_chunk(tokens, positions, block_tables, cols,
                                     temps)
-        fn = self._get_draft_chunk(tokens.shape[1])
-        with parallel_context(self.ctx):
-            self.draft_cache = fn(self.draft_params, self.draft_cache,
-                                  jnp.array(tokens, jnp.int32, copy=True),
-                                  jnp.array(positions, jnp.int32, copy=True),
-                                  jnp.array(block_tables, jnp.int32,
-                                            copy=True))
+        width = tokens.shape[1]
+        fn = self._get_draft_chunk(width)
+        with self.tracer.span("runner.prepare"):
+            args = (self.draft_params, self.draft_cache,
+                    jnp.array(tokens, jnp.int32, copy=True),
+                    jnp.array(positions, jnp.int32, copy=True),
+                    jnp.array(block_tables, jnp.int32, copy=True))
+        self.draft_cache = self._dispatch("draft_chunk", width, fn, args)
         return tok
 
     def _get_draft_chunk(self, width: int):
