@@ -19,11 +19,14 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref, sparsity
 from repro.kernels.admm_polarize import admm_polarize as _admm_polarize_kernel
 from repro.kernels.bitserial_crossbar import bitserial_crossbar as _bitserial_kernel
+from repro.kernels.paged_attention import \
+    paged_decode_attention as _paged_attention_kernel
 from repro.kernels.polarized_matmul import polarized_matmul as _polarized_kernel
 
 #: zero-skip modes for :func:`polarized_matmul` (DESIGN.md §6g):
@@ -246,6 +249,46 @@ def polarized_matmul(
                 x_, mg, sg, sc, m=m, bm=bm, bn=bn, bk=bk))
     return _mesh_polarized(x, mags, signs, scale, m=m, bm=bm, bn=bn, bk=bk,
                            skip=zero_skip == "block")
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention
+# ---------------------------------------------------------------------------
+
+def pool_lanes(hd: int) -> int:
+    """Lane width a KV page pool stores for head size ``hd``.
+
+    The paged-attention kernel DMAs whole pages out of the pool, and Mosaic
+    slices an HBM buffer only at whole 128-lane tiles, so on a TPU a pool
+    keeps ``hd`` padded with zeros to a multiple of 128 (the bytes a
+    row-major TPU layout of the unpadded pool would occupy anyway).  Off
+    the chip the interpreter slices anything, and the pool is ``hd`` wide.
+    """
+    return -(-hd // 128) * 128 if on_tpu() else hd
+
+
+def paged_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
+                    k_pool: jax.Array, v_pool: jax.Array, layer: jax.Array,
+                    pos: jax.Array, block_tables: jax.Array, *,
+                    window: Optional[int] = None) -> jax.Array:
+    """Single-token decode attention read in place from a KV page pool.
+
+    q: (B, 1, H, hd); k_new/v_new: (B, 1, kv, hd), the new token's rows at
+    position ``pos`` (B,); pools: (L, num_pages, page_size, kv, lanes)
+    (``lanes`` from :func:`pool_lanes`), read at ``layer`` through
+    ``block_tables`` (B, n_tables).  Returns (B, 1, H, hd) in the pool's
+    dtype — what ``models.layers.decode_attention`` computes on the
+    block-table gather of the pool with the new rows written at ``pos``.
+    """
+    hd = q.shape[-1]
+    lanes = k_pool.shape[-1]
+    pad = lambda x: _pad_to(x[:, 0], 2, lanes)
+    # 1 / sqrt(hd) in float32, as decode_attention scales its scores
+    scale = float(np.float32(1.0) / np.sqrt(np.float32(hd)))
+    out = _paged_attention_kernel(
+        pad(q), pad(k_new), pad(v_new), k_pool, v_pool, layer, pos,
+        block_tables, scale=scale, window=window, interpret=not on_tpu())
+    return out[:, None, :, :hd]
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,7 @@ Conventions
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -306,7 +306,8 @@ def attention_block(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
                     cache: Optional[Tuple[jax.Array, jax.Array]] = None,
                     cache_pos: Optional[jax.Array] = None,
                     use_rope: bool = True, causal: bool = True,
-                    return_kv: bool = False, dtype=jnp.bfloat16):
+                    return_kv: bool = False, dtype=jnp.bfloat16,
+                    attend: Optional[Callable[..., jax.Array]] = None):
     """Full attention sub-layer.  Returns (out, new_cache_kv_or_None).
 
     Train/prefill: ``cache=None`` -> causal self-attention over x;
@@ -320,6 +321,10 @@ def attention_block(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
     write targets a local TRANSIENT view either way; the caller commits
     the returned new-token K/V to the persistent cache (slot scatter or
     page scatter) after the layer scan.
+    Paged single-token decode: ``attend(q, k, v)`` reads the cache itself
+    (the page pool, in place) given the new token's post-rope q/k/v and
+    returns the (B, T, H, hd) attention output; the new-token K/V are
+    returned for the caller to commit, as above.
     """
     b, s, d = x.shape
     # Megatron-SP: gather the seq-sharded residual before the projections;
@@ -342,7 +347,10 @@ def attention_block(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
         q = apply_rope(q, positions, rope_theta)
         k = apply_rope(k, positions, rope_theta)
 
-    if cache is None:
+    if attend is not None:
+        out = attend(q, k, v)
+        new_cache = (k, v)
+    elif cache is None:
         out = causal_attention(q, k, v, window=window, q_chunk=q_chunk,
                                positions=positions, causal=causal)
         new_cache = (k, v) if return_kv else None

@@ -54,6 +54,9 @@ class Model:
     # T = 1 steady state, K+1 for a speculative verify (multi-token rows
     # commit via kv_cache.commit_tokens; past-table positions -> scratch)
     decode_paged: Optional[Callable[..., Tuple[jax.Array, Any]]] = None
+    # whether decode_paged reads K/V pages in place on single-token steps
+    # (kv_cache.reads_in_place; the dense family) — the others gather
+    paged_in_place: bool = False
 
     @property
     def supports_paged(self) -> bool:
@@ -127,5 +130,6 @@ def build(cfg: ModelConfig) -> Model:
         # moe is exact-length too: padded tokens would route through the
         # capacity-based dispatch and steal expert capacity from real tokens
         padded_prefill=cfg.family not in ("xlstm", "zamba", "moe"),
+        paged_in_place=cfg.family == "dense",
         **paged_kw,
     )

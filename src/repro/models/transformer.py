@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.distributed.sharding import current_context
+from repro.kernels import ops
 from repro.models import layers as L
 from repro.serving import kv_cache as KV
 
@@ -52,7 +54,7 @@ def init(cfg: ModelConfig, key) -> Params:
 
 def _block_apply(cfg: ModelConfig, bp: Params, x: jax.Array,
                  positions: jax.Array, cache, cache_pos, dtype, q_chunk: int,
-                 collect_kv: bool = False):
+                 collect_kv: bool = False, attend=None):
     with jax.named_scope("attention"):
         h, new_cache = L.attention_block(
             bp["attn"], L.rmsnorm(x, bp["norm1"], cfg.norm_eps),
@@ -60,7 +62,7 @@ def _block_apply(cfg: ModelConfig, bp: Params, x: jax.Array,
             rope_theta=cfg.rope_theta, positions=positions,
             window=cfg.sliding_window, q_chunk=q_chunk,
             cache=cache, cache_pos=cache_pos, return_kv=collect_kv,
-            dtype=dtype)
+            dtype=dtype, attend=attend)
         x = x + h
     with jax.named_scope("mlp"):
         mlp_in = L.rmsnorm(x, bp["norm2"], cfg.norm_eps)
@@ -127,10 +129,12 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                      slots: int, max_len: int, dtype=jnp.bfloat16
                      ) -> KV.PagedKVCache:
     """Page-pool cache: ``(L, num_pages, page_size, kv, hd)`` pools replace
-    the dense ``(L, slots, max_len, kv, hd)`` leaves (DESIGN.md §6d)."""
+    the dense ``(L, slots, max_len, kv, hd)`` leaves (DESIGN.md §6d); on a
+    TPU the last dim is ``hd`` zero-padded to whole 128-lane tiles
+    (``kernels.ops.pool_lanes``), so decode reads pages in place."""
     del slots, max_len
     kv, hd = cfg.num_kv_heads, cfg.hd()
-    shape = (cfg.num_layers, num_pages, page_size, kv, hd)
+    shape = (cfg.num_layers, num_pages, page_size, kv, ops.pool_lanes(hd))
     return KV.PagedKVCache(pool={"k": jnp.zeros(shape, dtype),
                                  "v": jnp.zeros(shape, dtype)},
                            dense={}, page_size=page_size)
@@ -189,34 +193,56 @@ def prefill_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
     prefix-shared pages)."""
     del slot
     logits, rows = _prefill_core(cfg, params, tokens, length)
-    return logits, KV.commit_pages(cache, rows, pages)
+    return logits, KV.commit_pages(cache, _to_pool(cache, rows), pages)
+
+
+def _to_pool(cache: KV.PagedKVCache, rows: Dict[str, jax.Array]
+             ) -> Dict[str, jax.Array]:
+    """New K/V rows zero-padded to the pool's lane width."""
+    lanes = cache.pool["k"].shape[-1]
+    return {n: jnp.pad(r, [(0, 0)] * (r.ndim - 1)
+                       + [(0, lanes - r.shape[-1])])
+            if r.shape[-1] != lanes else r for n, r in rows.items()}
 
 
 def _decode_core(cfg: ModelConfig, params: Params, tokens: jax.Array,
-                 k_cache: jax.Array, v_cache: jax.Array, pos: jax.Array):
+                 k_cache: Optional[jax.Array], v_cache: Optional[jax.Array],
+                 pos: jax.Array, attend=None):
     """Shared decode compute against ``(L, B, S, kv, hd)`` cache views
     (persistent dense leaves or block-table gathers — the per-slot
-    ``kpos <= pos`` masks are identical).  tokens: (B, T) with token t of
-    row b living at position ``pos[b] + t`` (T = 1 steady state, K+1 for a
-    speculative verify).  Returns (logits (B, T, V), new-token K/V of shape
-    (L, B, T, kv, hd)); committing them is the caller's job."""
+    ``kpos <= pos`` masks are identical), or, with ``attend(layer, q, k,
+    v)`` and no views, attention that reads the cache itself.  tokens:
+    (B, T) with token t of row b living at position ``pos[b] + t`` (T = 1
+    steady state, K+1 for a speculative verify).  Returns (logits (B, T,
+    V), new-token K/V of shape (L, B, T, kv, hd)); committing them is the
+    caller's job."""
     dtype = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     x = L.embed_lookup(params["embed"], tokens, dtype)
     positions = L.position_span(pos, t)
 
-    def body(x, xs):
-        bp, kc, vc = xs
-        out, new_cache = _block_apply(cfg, bp, x, positions, (kc, vc),
-                                      positions, dtype, L.DEFAULT_Q_CHUNK)
-        return out, new_cache
+    if attend is None:
+        def body(x, xs):
+            bp, kc, vc = xs
+            out, new_cache = _block_apply(cfg, bp, x, positions, (kc, vc),
+                                          positions, dtype, L.DEFAULT_Q_CHUNK)
+            return out, new_cache
+
+        xs = (params["blocks"], k_cache, v_cache)
+    else:
+        def body(x, xs):
+            bp, layer = xs
+            return _block_apply(cfg, bp, x, positions, None, None, dtype,
+                                L.DEFAULT_Q_CHUNK,
+                                attend=functools.partial(attend, layer))
+
+        xs = (params["blocks"], jnp.arange(cfg.num_layers, dtype=jnp.int32))
 
     # the scan's own per-layer slices (weights, K/V views) and stacked
     # new-token rows read as "layers"
     with jax.named_scope("layers"):
-        x, (k_tok, v_tok) = jax.lax.scan(body, x, (params["blocks"], k_cache,
-                                                   v_cache))
+        x, (k_tok, v_tok) = jax.lax.scan(body, x, xs)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.lm_logits(x, head_matrix(cfg, params), dtype)
     return logits, k_tok, v_tok
@@ -246,14 +272,35 @@ def decode_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                  cache: KV.PagedKVCache, pos: jax.Array,
                  block_tables: jax.Array
                  ) -> Tuple[jax.Array, KV.PagedKVCache]:
-    """Paged decode step: gather per-slot K/V views via the block tables,
-    attend exactly like :func:`decode_step`, commit the new tokens into
-    their pages (positions past the block table land in scratch)."""
-    b = tokens.shape[0]
+    """Paged decode step: attend exactly like :func:`decode_step`, then
+    commit the new tokens into their pages (positions past the block table
+    land in scratch).
+
+    A single-token step on an unsharded pool reads K/V in place: the layer
+    scan closes over the pools and each layer's attention is the paged
+    kernel (``kernels.ops.paged_attention``), walking the block tables.
+    Otherwise (T > 1: chunked prefill, speculative verify; or a pool sharded
+    over a mesh) per-slot K/V views are gathered through the block tables
+    first (``KV.reads_in_place``)."""
+    b, t = tokens.shape
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    views = KV.gather_views(cache, block_tables)
-    logits, k_tok, v_tok = _decode_core(cfg, params, tokens, views["k"],
-                                        views["v"], pos)
-    cache = KV.commit_tokens(cache, {"k": k_tok, "v": v_tok},
+    if KV.reads_in_place(t, current_context()):
+        pools = cache.pool
+
+        def attend(layer, q, k, v):
+            return ops.paged_attention(q, k, v, pools["k"], pools["v"],
+                                       layer, pos, block_tables,
+                                       window=cfg.sliding_window)
+
+        logits, k_tok, v_tok = _decode_core(cfg, params, tokens, None, None,
+                                            pos, attend=attend)
+    else:
+        views = KV.gather_views(cache, block_tables)
+        hd = cfg.hd()
+        if views["k"].shape[-1] != hd:
+            views = {n: v[..., :hd] for n, v in views.items()}
+        logits, k_tok, v_tok = _decode_core(cfg, params, tokens, views["k"],
+                                            views["v"], pos)
+    cache = KV.commit_tokens(cache, _to_pool(cache, {"k": k_tok, "v": v_tok}),
                              block_tables, pos)
     return logits, cache

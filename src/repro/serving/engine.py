@@ -314,6 +314,17 @@ class ModelRunner:
             self.tracer.count(f"runner.compiles.{program}.{width}")
         return out
 
+    def _count_kv_steps(self, steps: int, t: int,
+                        model: Optional[Model] = None) -> None:
+        """Count ``steps`` paged model steps of ``t`` tokens by how they
+        read K/V: ``decode.kv_in_place_steps`` (straight from the page
+        pool, ``KV.reads_in_place``) or ``decode.kv_gathered_steps``
+        (block-table gathers of per-slot views)."""
+        model = model if model is not None else self.model
+        in_place = model.paged_in_place and KV.reads_in_place(t, self.ctx)
+        self.tracer.count("decode.kv_in_place_steps" if in_place
+                          else "decode.kv_gathered_steps", steps)
+
     def hlo_texts(self) -> Dict[str, str]:
         """The compiled HLO text of each program called while the tracer
         was on, by ``"<program>.<width>"``.  A TPU profile names a device
@@ -394,6 +405,7 @@ class ModelRunner:
                     jnp.array(cols, jnp.int32, copy=True),
                     jnp.array(temps, jnp.float32, copy=True), sub)
         tok, self.cache = self._dispatch("chunk", width, fn, args)
+        self._count_kv_steps(1, width)
         with self.tracer.span("runner.wait"):
             return np.asarray(tok)
 
@@ -426,6 +438,8 @@ class ModelRunner:
             args += [jnp.array(temps, jnp.float32, copy=True), sub]
         toks_out, self.cache = self._dispatch("decode", self.decode_block,
                                               self._decode, args)
+        if self.paged:
+            self._count_kv_steps(self.decode_block, 1)
         with self.tracer.span("runner.wait"):
             return np.asarray(toks_out)
 

@@ -14,8 +14,11 @@ Device side (jit-safe, donated):
   pools (``(layers, num_pages, page_size, ...)`` per cache leaf) plus any
   leaves that stay slot-addressed (e.g. whisper's encoder output).
 * :func:`gather_views` — block-table gather producing the per-slot
-  contiguous ``(layers, slots, cap, ...)`` views decode attention consumes;
-  masks then derive from per-slot lengths exactly as on the dense cache.
+  contiguous ``(layers, slots, cap, ...)`` views multi-token decode
+  attention consumes; masks then derive from per-slot lengths exactly as
+  on the dense cache.  Single-token decode of the dense family reads the
+  pool in place instead (:func:`reads_in_place`,
+  ``kernels/paged_attention.py``).
 * :func:`commit_token` / :func:`commit_pages` — the decode-step scatter of
   one token row into its page, and the bulk-prefill one-shot write of whole
   pages.
@@ -94,6 +97,16 @@ def gather_views(cache: PagedKVCache, block_tables: jax.Array
             out[name] = v.reshape(v.shape[0], b, n * cache.page_size,
                                   *v.shape[4:])
     return out
+
+
+def reads_in_place(t: int, ctx=None) -> bool:
+    """Whether a T-token paged decode step reads K/V from the pool in place
+    (``kernels/paged_attention.py``) instead of :func:`gather_views`: a
+    single-token step on a pool held by one device.  ``ctx`` is the active
+    ``distributed.sharding.ParallelContext``; on a mesh the pool is sharded
+    and the kernel is not partitioned, so those steps gather, as do
+    multi-token steps (chunked prefill, speculative verify)."""
+    return t == 1 and (ctx is None or ctx.mesh.size == 1)
 
 
 def resolve_pages(block_tables: jax.Array, grid: jax.Array, page_size: int,

@@ -458,6 +458,8 @@ class SpeculativeRunner(ModelRunner):
                     jnp.array(temps, jnp.float32, copy=True), sub)
         out, n_emit, self.cache, self.draft_cache = self._dispatch(
             "speculate", k_round, fn, args)
+        self._count_kv_steps(k_round + 1, 1, self.draft_model)   # drafts
+        self._count_kv_steps(1, k_round + 1)                     # verify
         with self.tracer.span("runner.wait"):
             out = np.asarray(out)
             counts = np.asarray(n_emit, dtype=np.int64).astype(np.int32)
@@ -520,6 +522,7 @@ class SpeculativeRunner(ModelRunner):
                     jnp.array(positions, jnp.int32, copy=True),
                     jnp.array(block_tables, jnp.int32, copy=True))
         self.draft_cache = self._dispatch("draft_chunk", width, fn, args)
+        self._count_kv_steps(1, width, self.draft_model)
         return tok
 
     def _get_draft_chunk(self, width: int):

@@ -388,5 +388,23 @@ def check_repair():
     print("repair ok:", h["flagged"][leaf]["bad_codes"], "codes repaired")
 
 
+def check_kv_counters():
+    """On a mesh the page pool is sharded and the paged-attention kernel is
+    not partitioned, so every paged model step reads gathered views: the
+    engine counts gathered steps only (decode rounds and chunk calls)."""
+    m = _tiny_model()
+    params = m.init(jax.random.PRNGKey(0))
+    mesh = make_mesh(MeshConfig(data=2, model=1))
+    eng = ServingEngine(m, params, max_len=32, batch_slots=2, page_size=4,
+                        mesh=mesh,
+                        slo={"prefill_chunk": 4, "step_token_budget": 8})
+    eng.run(_requests(3, new=5))
+    c = eng.stats()["counters"]
+    assert "decode.kv_in_place_steps" not in c, c
+    assert c["decode.kv_gathered_steps"] == (
+        c["decode.rounds"] * eng.decode_block + c["prefill.calls"]), c
+    print("kv_counters ok:", c["decode.kv_gathered_steps"])
+
+
 if __name__ == "__main__":
     globals()[f"check_{sys.argv[1]}"]()

@@ -12,6 +12,7 @@ imports every test file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,8 @@ from repro.kernels import ops
 from repro.models.registry import build
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
+# the paged-attention kernel's custom call is named after the kernel
+PAGED_KERNEL = r"%paged_attention[.\d]* = [^\n]*tpu_custom_call"
 
 
 @pytest.fixture(scope="module")
@@ -139,8 +142,41 @@ def test_qwen2_full_width_compressed_decode_compiles_for_v5e(
     compiled = jax.jit(fn).lower(
         *_on(one_chip, (params, toks, cache, pos) + extra)).compile()
     text = compiled.as_text()
-    # q, k, v, o, gate, up, down: the layer scan's body holds each once
-    assert text.count(KERNEL) == 7, text.count(KERNEL)
+    # q, k, v, o, gate, up, down: the layer scan's body holds each once;
+    # the paged step adds the paged-attention kernel, which reads the pool
+    # in place: no op of the program gathers per-slot views
+    paged = step == "decode_paged"
+    assert text.count(KERNEL) == 7 + paged, text.count(KERNEL)
+    assert bool(re.search(PAGED_KERNEL, text)) == paged
+    assert "/kv_gather/" not in text
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 10**9), mem
+
+
+@pytest.mark.parametrize("arch,window", [("qwen2", None), ("danube", 4096)])
+def test_paged_attention_compiles_for_v5e(one_chip, tpu_routing, arch,
+                                          window):
+    """The paged-attention kernel at the decode_heavy cells' widths (32
+    slots x 64 pages of 16 rows): qwen2-1.5b (12/2 heads of 128, 28
+    layers) and h2o-danube-1.8b (32/8 heads of 80 in a pool padded to 128
+    lanes, 24 layers, window 4096).  It reads the pools where they are:
+    the program makes no copy of them."""
+    layers, heads, kv, hd = {"qwen2": (28, 12, 2, 128),
+                             "danube": (24, 32, 8, 80)}[arch]
+    slots, n_tables, page = 32, 64, 16
+    pool = (layers, slots * n_tables + 1, page, kv, ops.pool_lanes(hd))
+    bf = jnp.bfloat16
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((slots, 1, heads, hd), bf),
+        jax.ShapeDtypeStruct((slots, 1, kv, hd), bf),
+        jax.ShapeDtypeStruct((slots, 1, kv, hd), bf),
+        jax.ShapeDtypeStruct(pool, bf), jax.ShapeDtypeStruct(pool, bf),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots, n_tables), jnp.int32)))
+    compiled = jax.jit(functools.partial(ops.paged_attention,
+                                         window=window)).lower(
+        *args).compile()
+    assert re.search(PAGED_KERNEL, compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20

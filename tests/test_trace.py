@@ -4,7 +4,10 @@ spans and counters of a served run, compile counts per program and width,
 and the named scopes on the device step."""
 import collections
 import dataclasses
+import os
 import re
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -155,9 +158,15 @@ def test_fleet_run_spans_and_counters(tiny, chunked):
     assert c["decode.tokens"] == served - len(reqs)   # first tokens: prefill
     assert c["prefill.tokens"] == sum(len(r.prompt) for r in reqs)
     assert c["decode.rows"] == c["decode.rounds"] * 2 * eng.decode_block
+    # decode rounds read K/V in place; only chunk calls gather
+    assert c["decode.kv_in_place_steps"] == (c["decode.rounds"]
+                                             * eng.decode_block)
     if chunked:
         assert st["slo"]["chunked_prefill"] == {
             "calls": c["prefill.calls"], "tokens": c["prefill.tokens"]}
+        assert c["decode.kv_gathered_steps"] == c["prefill.calls"]
+    else:
+        assert "decode.kv_gathered_steps" not in c
     # request spans: queue <= prefill <= decode for every request
     per = collections.defaultdict(dict)
     for s in spans:
@@ -225,6 +234,41 @@ def test_compiles_counted_per_program_and_width(tiny):
     assert eng.stats()["counters"]["runner.compiles.decode.4"] == 1
 
 
+def test_kv_step_counters_split_in_place_from_gathered(tiny):
+    """A plain paged decode round reads K/V straight from the pool: it adds
+    ``decode_block`` to ``decode.kv_in_place_steps``; a chunked-prefill
+    call reads gathered views: it adds 1 to ``decode.kv_gathered_steps``."""
+    eng = _engine(tiny)
+    runner, slots, blk = eng.runner, eng.slots, eng.decode_block
+    zi, zf = np.zeros(slots, np.int32), np.zeros(slots, np.float32)
+    tables = np.zeros_like(eng.scheduler.block_tables)
+    counters = lambda: dict(eng.stats()["counters"])
+    runner.decode_round(zi, zi, zf, block_tables=tables, active=[False] * 2)
+    c = counters()
+    assert c["decode.kv_in_place_steps"] == blk
+    assert "decode.kv_gathered_steps" not in c
+    runner.prefill_chunk(np.zeros((slots, 8), np.int32), zi, tables, zi, zf)
+    c = counters()
+    assert c["decode.kv_gathered_steps"] == 1
+    assert c["decode.kv_in_place_steps"] == blk
+    runner.decode_round(zi, zi, zf, block_tables=tables, active=[False] * 2)
+    assert counters()["decode.kv_in_place_steps"] == 2 * blk
+
+
+def test_mesh_engine_counts_gathered_steps_only():
+    """A pool sharded over a mesh is read through gathered views on every
+    step; the counters of a served run on a data=2 mesh say so.  Runs in a
+    child process with two host devices (the pytest process keeps one)."""
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "_sharded_child.py"),
+         "kv_counters", "2"],
+        capture_output=True, text=True, timeout=560, env=env, cwd=root)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "kv_counters ok" in proc.stdout
+
+
 # ---------------------------------------------------------------------------
 # named scopes on the device step
 # ---------------------------------------------------------------------------
@@ -244,11 +288,16 @@ def test_device_programs_carry_named_scopes(tiny):
     chunk = r._get_chunk(8).lower(r.params, r.cache,
                                   np.zeros((slots, 8), np.int32), zi, tables,
                                   zi, zf, key)
-    for lowered in (dec, chunk):
+    for lowered, scopes in ((dec, ("kv_commit", "attention", "mlp",
+                                   "lm_head", "sampling")),
+                            (chunk, ("kv_gather", "kv_commit", "attention",
+                                     "mlp", "lm_head", "sampling"))):
         names = "\n".join(_op_names(lowered.compile().as_text()))
-        for scope in ("kv_gather", "kv_commit", "attention", "mlp",
-                      "lm_head", "sampling"):
+        for scope in scopes:
             assert f"/{scope}/" in names, scope
+    # single-token decode reads the pages in place: nothing is gathered
+    assert "/kv_gather/" not in "\n".join(
+        _op_names(dec.compile().as_text()))
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -277,4 +326,7 @@ def test_compiled_programs_kept_only_while_traced(tiny):
     hlo = eng.stats()["trace"]["hlo"]
     assert "decode.4" in hlo and any(k.startswith("chunk.") for k in hlo)
     assert hlo["decode.4"].startswith("HloModule jit__decode_fn")
-    assert "/kv_gather/" in hlo["decode.4"]
+    assert "/kv_commit/" in hlo["decode.4"]
+    assert "/kv_gather/" not in hlo["decode.4"]
+    assert all("/kv_gather/" in text for k, text in hlo.items()
+               if k.startswith("chunk."))
