@@ -4,12 +4,14 @@
 
 For each seed it makes a short run of the cell as ``run.py`` does (the same
 set-up, traffic, engine and window), then puts the control in the program's
-place over the same sample of finished requests: the reference computed in
-8-bit floating point picks each token, and the float32 reference reads its
-gap.  Those gaps go through the run's own comparison (``run.judge``), which
-has to find the control not correct.  One line of JSON per seed on standard
-output, with the program's widest gap and ``correct`` and the control's.
-The benchmark's own runs never run this.
+place over the same sample of finished requests: the configuration's
+family (``chipbench/families/<family>.py``, its ``gaps`` with ``control``)
+has its reference, computed a precision step below the configuration's
+(8-bit floating point for bfloat16), pick each token, and its float32
+reference read the gap.  Those gaps go through the run's own comparison
+(``run.judge``), which has to find the control not correct.  One line of
+JSON per seed on standard output, with the program's widest gap and
+``correct`` and the control's.  The benchmark's own runs never run this.
 """
 from __future__ import annotations
 

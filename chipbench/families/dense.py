@@ -1,11 +1,36 @@
-"""Plain reference of the served model, independent of the program.
+"""The dense family: decoder-only transformers with GQA and SwiGLU.
 
-Nothing here imports the program.  The module holds
+A family module (``chipbench/families/<family>.py``) is found by the
+``"family"`` of a configuration file, so a configuration of another family
+comes as one new file here.  Nothing in a family imports the program.  It
+holds
 
-* the benchmark's weight generator: random float32 weights of a dense
-  decoder-only transformer, made from the seed in one jitted call, in the
-  parameter layout the program's dense family reads (``embed``, stacked
-  ``blocks``, ``final_norm``, an untied ``head``);
+* ``engine_params(mc, forms, seed)``: the weights handed to
+  ``ServingEngine``, in a form the engine accepts, made from the seed;
+* ``gaps(mc, forms, seed, prompts, served, pad_len, pad_served, control)``:
+  per served token of the requests given, the float32 reference's best
+  logit minus its logit of the served token, or with ``control`` of the
+  token the control ranks first.  The family builds its reference weights
+  from the seed itself, projected as FORMS;
+* the work counts that metric readers take as ``run.work``, under the
+  conventions of ``work.py``: ``projected_matmuls``, ``matmul_params``,
+  ``token_flops``, ``decode_flops``, ``prefill_flops``,
+  ``kv_bytes_per_position``, ``decode_weight_bytes``,
+  ``kernel_least_seconds``.
+
+Memory: a new family's generator must not need the whole float32 tree at
+once.  Derive each layer's key from the seed and the layer index (for
+example ``jax.random.fold_in(weight_key(seed), layer)``), so that the
+reference can rebuild, project and run one layer at a time.  This family
+makes its whole tree in one call (6 GB for qwen2-1.5b) and keeps that
+generator, so that its cells keep their weights.
+
+This family holds
+
+* its weight generator: random float32 weights of a dense decoder-only
+  transformer, made from the seed in one jitted call, in the parameter
+  layout the program's dense family reads (``embed``, stacked ``blocks``,
+  ``final_norm``, an untied ``head``);
 * a plain FORMS projection of those weights: per m-row fragment a sign
   (the one that keeps more squared magnitude), entries of the other sign
   zeroed, then per-column max-abs scaling onto 2**bits - 1 magnitude
@@ -17,20 +42,23 @@ Nothing here imports the program.  The module holds
   next to nothing else on one chip;
 * the control: the same forward with every matmul operand rounded to 8-bit
   floating point (e4m3, per-tensor scale), the precision step below the
-  configuration's bfloat16.
+  configuration's bfloat16;
+* its operation and byte counts.
 
-``gaps`` compares served tokens with the reference: at each position where
-a token was served, how far the reference's logit of that token lies below
-the reference's best logit.
+``request_gaps`` compares one request's served tokens with the reference:
+at each position where a token was served, how far the reference's logit of
+that token lies below the reference's best logit.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import work
 
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512
@@ -250,8 +278,9 @@ def _pad(n: int, block: int) -> int:
     return -(-n // block) * block
 
 
-def gaps(params: Dict, mc: Dict, prompt: np.ndarray, served: np.ndarray,
-         pad_len: int, pad_served: int, control: bool = False) -> np.ndarray:
+def request_gaps(params: Dict, mc: Dict, prompt: np.ndarray,
+                 served: np.ndarray, pad_len: int, pad_served: int,
+                 control: bool = False) -> np.ndarray:
     """Per served token, the reference's best logit minus its logit of the
     served token (``control=True``: of the token the 8-bit forward ranks
     first at that position).
@@ -277,3 +306,103 @@ def gaps(params: Dict, mc: Dict, prompt: np.ndarray, served: np.ndarray,
     else:
         out = _gaps_program(params, tokens, idx, tok, frozen)
     return np.asarray(out)[:t]
+
+
+def gaps(mc: Dict, forms: Dict, seed: int, prompts: Sequence[np.ndarray],
+         served: Sequence[np.ndarray], pad_len: int, pad_served: int,
+         control: bool = False) -> np.ndarray:
+    """``request_gaps`` of each request in turn, concatenated, against the
+    seed's weights projected as ``forms`` (freed before returning)."""
+    params = project_params(make_params(mc, seed), forms["m"], forms["bits"])
+    out = [request_gaps(params, mc, p, np.asarray(s, np.int32), pad_len,
+                        pad_served, control=control)
+           for p, s in zip(prompts, served)]
+    del params
+    return np.concatenate(out) if out else np.zeros(0, np.float32)
+
+
+def engine_params(mc: Dict, forms: Dict, seed: int) -> Dict:
+    """The float32 tree of the seed; the engine compresses it to ``forms``."""
+    return make_params(mc, seed)
+
+
+# --------------------------------------------------------------------------
+# operations and bytes (work.py's conventions)
+# --------------------------------------------------------------------------
+
+def layer_matmuls(mc: Dict) -> List[Tuple[str, int, int]]:
+    """(name, K, N) of the matmuls of one block."""
+    _, d, h, kv, hd, f, _ = _shapes(mc)
+    return [("wq", d, h * hd), ("wk", d, kv * hd), ("wv", d, kv * hd),
+            ("wo", h * hd, d), ("gate", d, f), ("up", d, f), ("down", f, d)]
+
+
+def projected_matmuls(mc: Dict) -> List[Tuple[str, int, int, int]]:
+    """(name, K, N, calls per token step) of every matmul the polarized
+    kernel serves: the block matmuls once per layer, an untied head once."""
+    L, d, *_, v = _shapes(mc)
+    out = [(n, k, nn, L) for n, k, nn in layer_matmuls(mc)]
+    if not mc["tie_embeddings"]:
+        out.append(("head", d, v, 1))
+    return out
+
+
+def matmul_params(mc: Dict) -> int:
+    """Matmul parameters touched per token, the head included."""
+    L, d, *_, v = _shapes(mc)
+    return L * sum(k * n for _, k, n in layer_matmuls(mc)) + d * v
+
+
+def attended(mc: Dict, ctx: int) -> int:
+    """Positions one query attends to at context length ``ctx``."""
+    w = mc.get("sliding_window")
+    return min(ctx, w) if w else ctx
+
+
+def token_flops(mc: Dict, ctx: int) -> float:
+    """Model operations of one token whose query sees ``ctx`` positions."""
+    L, _, h, _, hd, *_ = _shapes(mc)
+    return 2.0 * matmul_params(mc) + 4.0 * L * h * hd * attended(mc, ctx)
+
+
+def decode_flops(mc: Dict, tokens: int, positions: int) -> float:
+    """Operations of ``tokens`` decoded tokens whose queries read
+    ``positions`` K/V positions in all (per layer)."""
+    L, _, h, _, hd, *_ = _shapes(mc)
+    return 2.0 * matmul_params(mc) * tokens + 4.0 * L * h * hd * positions
+
+
+def prefill_flops(mc: Dict, start: int, n: int) -> float:
+    """Operations of prompt tokens at positions ``start .. start + n - 1``."""
+    L, _, h, _, hd, *_ = _shapes(mc)
+    w = mc.get("sliding_window")
+    if w:
+        ctx = sum(attended(mc, p + 1) for p in range(start, start + n))
+    else:
+        ctx = n * start + n * (n + 1) // 2
+    return 2.0 * matmul_params(mc) * n + 4.0 * L * h * hd * ctx
+
+
+def kv_bytes_per_position(mc: Dict) -> int:
+    L, _, _, kv, hd, *_ = _shapes(mc)
+    return L * 2 * kv * hd * work.BF16
+
+
+def decode_weight_bytes(mc: Dict, forms: Dict, rows: int) -> float:
+    """Weight bytes one decode step over ``rows`` token rows must read."""
+    L, d, h, kv, hd, f, v = _shapes(mc)
+    total = sum(calls * work.projected_bytes(k, n, forms)
+                for _, k, n, calls in projected_matmuls(mc))
+    if mc["tie_embeddings"]:
+        total += v * d * work.BF16                # the embedding as head
+    total += rows * d * work.BF16                 # embedding rows looked up
+    total += (2 * L + 1) * d * work.BF16          # norms
+    if mc["qkv_bias"]:
+        total += L * (h + 2 * kv) * hd * work.BF16
+    return total
+
+
+def kernel_least_seconds(mc: Dict, forms: Dict, rows: int, steps: int,
+                         peak: Dict) -> Tuple[float, float]:
+    """``work.least_seconds`` of this configuration's projected matmuls."""
+    return work.least_seconds(projected_matmuls(mc), forms, rows, steps, peak)

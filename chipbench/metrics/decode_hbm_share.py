@@ -1,8 +1,8 @@
 """Bytes the traced decode rounds must read over (their device time x the
 chip's HBM bandwidth), in percent.  Per model step: the served weights in
-their stored format (work.decode_weight_bytes) plus the K/V of each active
-slot's live positions; device time is that of the decode program's events."""
-import work
+their stored format (the family's ``decode_weight_bytes``) plus the K/V of
+each active slot's live positions; device time is that of the decode
+program's events."""
 
 
 def read(run):
@@ -13,8 +13,9 @@ def read(run):
     if not calls:
         return None
     secs = tr.seconds_in(tr.module_events("jit__decode_fn"), calls)
-    per_pos = work.kv_bytes_per_position(run.model)
-    need = sum(steps * work.decode_weight_bytes(run.model, run.forms, rows)
+    per_pos = run.work.kv_bytes_per_position(run.model)
+    need = sum(steps * run.work.decode_weight_bytes(run.model, run.forms,
+                                                    rows)
                + kv * per_pos for _, _, (rows, steps, kv, _) in calls)
     busy = sum(secs)
     return 100.0 * need / (busy * run.peak["hbm_bytes_per_s"]) if busy else None

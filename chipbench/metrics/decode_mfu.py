@@ -2,8 +2,8 @@
 decode program's events x the chip's bf16 peak), in percent: the whole
 decode step's share of the chip's peak, beside the polarized kernel's share
 of its roofline.  Per round: each active row's steps through every matmul
-(work.matmul_params) plus attention over the K/V positions it read."""
-import work
+(the family's ``matmul_params``) plus attention over the K/V positions it
+read."""
 
 
 def read(run):
@@ -14,6 +14,6 @@ def read(run):
     busy = sum(tr.seconds_in(tr.module_events("jit__decode_fn"), calls))
     if not busy:
         return None
-    ops = sum(work.decode_flops(run.model, active * steps, kv)
+    ops = sum(run.work.decode_flops(run.model, active * steps, kv)
               for _, _, (_, steps, kv, active) in calls)
     return 100.0 * ops / (busy * run.peak["bf16_flops"])

@@ -5,21 +5,23 @@
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its files are
 found by name: ``chipbench/cells/<cell>.json`` (the cell's configuration,
 traffic mix and correctness limit), ``chipbench/configs/<config>.json``
-(model sizes, FORMS format, page size), ``chipbench/traffic/<mix>.json``
-(arrivals, lengths, slots) and ``chipbench/metrics/<metric>.py`` (one reader
-per metric).
+(model sizes, FORMS format, page size, family),
+``chipbench/families/<family>.py`` (the family's weights, plain reference
+and work counts), ``chipbench/traffic/<mix>.json`` (arrivals, lengths,
+slots) and ``chipbench/metrics/<metric>.py`` (one reader per metric).
 
-A run makes the weights from the seed on the device, builds a
+A run has the family make the weights from the seed, builds a
 ``ServingEngine`` on the fleet scheduler, warms up every program shape the
 mix can use, then serves the mix's requests through ``ServingEngine.run``
 and times the calls the scheduler makes into the runner; a fixed window
-stops serving when it closes.  ``--trace 0``
-reports the cell's end-to-end metrics, ``--trace 1`` its per-layer metrics
-from a profiler trace of a slice of the window.  After the window the
-program is freed and a plain float32 reference (``reference.py``) checks a
-sample of the finished requests, drawn across the batch's slots.  The last line of standard output is the
-result; the run exits non-zero without printing one when JAX finds no TPU or
-fewer chips than the cell asks for.
+stops serving when it closes; a ``drain`` window serves every request to
+its end.  ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
+its per-layer metrics from a profiler trace of a slice of the window.  After
+the window the program is freed and the family's plain float32 reference
+checks a sample of the finished requests, drawn across the batch's slots.
+The last line of standard output is the result; the run exits non-zero
+without printing one when JAX finds no TPU or fewer chips than the cell
+asks for.
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -41,10 +44,11 @@ import numpy as np  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 CACHE = os.path.join(ROOT, ".chipbench_cache")
+CELLS, CONFIGS, FAMILIES = (os.path.join(HERE, d)
+                            for d in ("cells", "configs", "families"))
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-import reference  # noqa: E402
 import timeline  # noqa: E402
 import traffic  # noqa: E402
 # the system under test
@@ -67,13 +71,30 @@ def load_json(*parts: str) -> Dict:
         return json.load(f)
 
 
-def load_reader(name: str):
-    """The ``read(run)`` function of ``chipbench/metrics/<name>.py``."""
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location("metric_" + name, path)
+def load_module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str):
+    """The ``read(run)`` function of ``chipbench/metrics/<name>.py``."""
+    return load_module(os.path.join(HERE, "metrics", name + ".py"),
+                       "metric_" + name).read
+
+
+@functools.lru_cache(maxsize=None)
+def _family_at(path: str):
+    return load_module(path, "family_" + os.path.basename(path)[:-3])
+
+
+def load_family(name: str):
+    """The module ``chipbench/families/<name>.py``, loaded once: a
+    configuration family's ``engine_params``, its plain reference's
+    ``gaps`` and the work counts metric readers take as ``run.work`` (each
+    family's docstring sets them out)."""
+    return _family_at(os.path.join(FAMILIES, name + ".py"))
 
 
 def cell_metrics(bench: Dict, cell: str, trace: bool) -> List[Dict]:
@@ -87,7 +108,7 @@ def load_cell(bench: Dict, name: str) -> Dict:
     entry = next((w for w in bench["workloads"] if w["name"] == name), None)
     if entry is None:
         raise SystemExit(f"chipbench: no workload {name!r} in BENCHMARK.json")
-    cell = load_json(HERE, "cells", name + ".json")
+    cell = load_json(CELLS, name + ".json")
     if (cell["config"], cell["traffic"]) != (entry["config"], entry["traffic"]):
         raise SystemExit(f"chipbench: cells/{name}.json names "
                          f"{cell['config']}/{cell['traffic']}, BENCHMARK.json "
@@ -100,9 +121,9 @@ def load_cell(bench: Dict, name: str) -> Dict:
             raise SystemExit(f"chipbench: a backlog's backlog_rate belongs in "
                              f"cells/{name}.json, not in the mix")
         mix["backlog_rate"] = cell["backlog_rate"]
-    return dict(cell, name=name, chips=entry["chips"],
-                config_file=load_json(HERE, "configs", cell["config"] + ".json"),
-                mix=mix)
+    config_file = load_json(CONFIGS, cell["config"] + ".json")
+    return dict(cell, name=name, chips=entry["chips"], config_file=config_file,
+                family=load_family(config_file["family"]), mix=mix)
 
 
 def cache_entries() -> int:
@@ -252,20 +273,15 @@ def reference_gaps(cell: Dict, seed: int, planned: Dict[int, Any],
                    served: Dict[int, List[int]], sample: List[int],
                    control: bool = False) -> np.ndarray:
     """Per checked token, the reference's best logit minus its logit of the
-    served token (``control``: of the token the 8-bit reference puts
-    first), over the sampled requests."""
+    served token (``control``: of the token the control puts first), over
+    the sampled requests: the family's ``gaps``."""
     if not sample:
         return np.zeros(0, np.float32)
     cf, mix = cell["config_file"], cell["mix"]
-    mc = cf["model"]
-    params = reference.project_params(reference.make_params(mc, seed),
-                                      cf["forms"]["m"], cf["forms"]["bits"])
-    out = [reference.gaps(params, mc, planned[u].prompt,
-                          np.asarray(served[u], np.int32), mix["max_len"],
-                          mix["output_len"]["max"], control=control)
-           for u in sample]
-    del params
-    return np.concatenate(out)
+    return cell["family"].gaps(
+        cf["model"], cf["forms"], seed, [planned[u].prompt for u in sample],
+        [served[u] for u in sample], mix["max_len"], mix["output_len"]["max"],
+        control=control)
 
 
 def judge(cell: Dict, run: timeline.Run, sample: List[int], gaps: np.ndarray
@@ -335,7 +351,7 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
     planned = traffic.generate(mix, seed, seconds, mc["vocab_size"])
     by_uid = {p.uid: p for p in planned}
 
-    params = reference.make_params(mc, seed)
+    params = cell["family"].engine_params(mc, cf["forms"], seed)
     engine = build_engine(cell, params, seed)
     del params
     if engine_hook is not None:
@@ -412,11 +428,8 @@ def run_cell(bench: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
         log(f"chipbench: open-loop lag (due time to the start of the next "
             f"call into the runner), ms: p50 {np.percentile(lag, 50):.3f}, "
             f"p99 {np.percentile(lag, 99):.3f}, max {lag.max():.3f}")
-        quarters = np.array_split(sorted(planned, key=lambda p: p.arrival_s), 4)
-        ttft = [np.median([(rec.tracks[p.uid].first_t or np.inf) - run.due(p.uid)
-                           for p in q]) * 1e3 for q in quarters if len(q)]
         log(f"chipbench: median time to first token by quarter of arrivals, "
-            f"ms: {', '.join(f'{x:.1f}' for x in ttft)}")
+            f"ms: {', '.join(f'{x:.1f}' for x in run.quarter_ttft_ms())}")
     log(f"chipbench: peak_bytes_in_use after the window {mem}; compilation "
         f"cache entries {entries} at start, {cache_entries()} after the window")
 

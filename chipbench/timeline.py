@@ -20,6 +20,7 @@ ones checked.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 from typing import Any, Dict, List, Optional
 
@@ -235,6 +236,12 @@ class Run:
         return self.cell["config_file"]["forms"]
 
     @property
+    def work(self) -> Any:
+        """The configuration's family module: its operation and byte counts
+        (``work.py``'s conventions)."""
+        return self.cell["family"]
+
+    @property
     def tracks(self) -> Dict[int, Track]:
         return self.recorder.tracks
 
@@ -279,6 +286,20 @@ class Run:
 
     def due(self, uid: int) -> float:
         return self.t0 + self.planned[uid].arrival_s
+
+    def ttft_s(self) -> Dict[int, float]:
+        """Per request, seconds from its due time to its first token; inf
+        for a miss: a request that failed or got no first token."""
+        failed = set(self.failed_uids())
+        return {u: math.inf if u in failed or t.first_t is None
+                else t.first_t - self.due(u) for u, t in self.tracks.items()}
+
+    def quarter_ttft_ms(self) -> List[float]:
+        """Median time to first token of each quarter of the arrivals, ms."""
+        first = self.ttft_s()
+        order = sorted(self.planned.values(), key=lambda p: (p.arrival_s, p.uid))
+        return [float(np.median([first[p.uid] for p in q])) * 1e3
+                for q in np.array_split(order, 4) if len(q)]
 
     def failed_uids(self) -> List[int]:
         """Requests served short, long or with a token outside the

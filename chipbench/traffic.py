@@ -5,10 +5,11 @@ length distributions and the serving sizes; this module turns it and a seed
 into requests.  Every seed gets the same set of (prompt length, output
 length) pairs and the same inter-arrival gaps (stratified quantiles of the
 mix's distributions, paired by a fixed shuffle), with token ids drawn from
-the seed.  With ``"order": "seed"`` the seed also draws the order, so two
-seeds offer the same work in another order; with ``"order": "fixed"`` every
-seed offers it in one fixed order, for a window that holds too few requests
-for the order to average out.
+the seed.  With ``"order": "seed"`` the seed also draws the order of the
+requests and of the gaps, so two seeds offer the same work in another
+order; with ``"order": "fixed"`` every seed offers it in one fixed order,
+arrival times included, for a window that holds too few requests for the
+order to average out.
 
 Arrivals:
 
@@ -98,7 +99,9 @@ def generate(mix: Dict, seed: int, seconds: float, vocab: int
     elif mix["arrivals"] == "poisson":
         k = max(n - 1, 1)
         gaps = -np.log1p(-(np.arange(k) + 0.5) / k) / mix["rate"]
-        arrivals = np.concatenate([[0.0], np.cumsum(rng.permutation(gaps))])[:n]
+        gaps = (rng.permutation(gaps) if mix["order"] == "seed" else
+                np.random.default_rng(PAIRING_SEED + 2).permutation(gaps))
+        arrivals = np.concatenate([[0.0], np.cumsum(gaps)])[:n]
     else:
         raise ValueError(f"unknown arrival process {mix['arrivals']!r}")
     reqs = [Planned(uid=i,
