@@ -17,6 +17,20 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), under the keys of a Hugging
+    Face ``rope_scaling`` group of type ``yarn``."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+    type: str = "yarn"
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture description for every family in the zoo."""
 
@@ -38,13 +52,25 @@ class ModelConfig:
     dtype: str = "bfloat16"
 
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0            # the router's width
     experts_per_token: int = 0
     moe_d_ff: int = 0
     num_shared_experts: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # training dispatch only (serving drops none)
     moe_dispatch_int8: bool = False   # DeepSeek-style quantized all_to_all
+    first_k_dense_replace: int = 0  # leading layers with a dense d_ff MLP
+    router_scoring: str = "softmax"  # softmax | sigmoid
+    router_bias: bool = False       # selection-only correction bias (noaux_tc)
+    n_group: int = 1                # expert groups; the top topk_group survive
+    topk_group: int = 1
+    norm_topk_prob: bool = True     # renormalize the top-k weights
+    routed_scaling_factor: float = 1.0
+    # expert parallelism: this chip holds experts expert_first ..
+    # expert_first + experts_held - 1 of every MoE layer (0 = all of them)
+    experts_held: int = 0
+    expert_first: int = 0
     mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnConfig] = None
     mtp: bool = False               # DeepSeek multi-token-prediction module
 
     # --- enc-dec (whisper) ---
@@ -71,8 +97,19 @@ class ModelConfig:
     act_fragment: int = 8           # sparsification granularity; align with
                                     # the serving FormsSpec.m to skip work
 
+    def __post_init__(self):
+        # a configuration file gives the nested groups as JSON objects
+        for name, cls in (("mla", MLAConfig), ("rope_scaling", YarnConfig)):
+            value = getattr(self, name)
+            if isinstance(value, dict):
+                object.__setattr__(self, name, cls(**value))
+
     def hd(self) -> int:
         return self.head_dim if self.head_dim is not None else self.d_model // self.num_heads
+
+    def held_experts(self) -> int:
+        """Routed experts whose weights this model holds per MoE layer."""
+        return self.experts_held or self.num_experts
 
     @property
     def attention_free(self) -> bool:
@@ -104,20 +141,23 @@ class ModelConfig:
             shared = 4 * attn + 3 * d * self.d_ff
             return L * per + shared + 2 * self.vocab_size * d
         ff = 3 * d * self.d_ff if self.d_ff else 0
-        if self.num_experts:
-            ff = 3 * d * self.moe_d_ff * (self.num_experts + self.num_shared_experts) + d * self.num_experts
         per = attn + ff
-        enc = self.encoder_layers * per
         emb = (1 if self.tie_embeddings else 2) * self.vocab_size * d
+        if self.num_experts:
+            k = self.first_k_dense_replace
+            moe = (3 * d * self.moe_d_ff
+                   * (self.held_experts() + self.num_shared_experts)
+                   + d * self.num_experts)
+            return k * per + (L - k) * (attn + moe) + emb
         return (L + self.encoder_layers) * per + emb
 
     def active_param_count(self) -> int:
         """Active params per token (MoE: only routed-in experts)."""
         if not self.num_experts:
             return self.param_count()
-        d, L = self.d_model, self.num_layers
+        d, L = self.d_model, self.num_layers - self.first_k_dense_replace
         total = self.param_count()
-        all_experts = L * 3 * d * self.moe_d_ff * self.num_experts
+        all_experts = L * 3 * d * self.moe_d_ff * self.held_experts()
         active_experts = L * 3 * d * self.moe_d_ff * self.experts_per_token
         return total - all_experts + active_experts
 

@@ -158,10 +158,12 @@ def is_crossbar_weight(path: str, shape: Tuple[int, ...]) -> bool:
     Biases, norms, per-channel recurrence params (rank 0/1) are digital-domain
     and excluded (paper stores only magnitude bits of MVM weights on ReRAM);
     the SSM depthwise conv and decay/step params are not MVMs; embedding
-    tables are lookups, not MVMs — excluded by name.
+    tables are lookups, not MVMs — excluded by name, as is an MoE router,
+    whose scores pick the experts and stay in float.
     """
     lname = path.lower()
-    if any(t in lname for t in ("embed", "bias", "scale", "norm", "a_log",
+    if any(t in lname for t in ("embed", "router", "bias", "scale", "norm",
+                                "a_log",
                                 "dt_", "conv_w", "conv_b", "conv1d", "lambda",
                                 "d_skip", "/bf", "/ro", "/rz", "/ri", "/rf",
                                 # QKV / MLP bias vectors (scan-stacked they are

@@ -31,7 +31,8 @@ from repro.forms.spec import FormsSpec
 from repro.forms.tree import (CompressedParams, CompressReport,
                               compress_tree, compressed_paths,
                               decompress_tree, shard_tree, spec_for_path,
-                              tree_sharding_specs, validate_tree_sharding)
+                              stack_streams, tree_sharding_specs,
+                              validate_tree_sharding)
 
 __all__ = [
     "FormsSpec", "FormsLinearParams", "from_dense", "to_dense", "apply",
@@ -39,7 +40,7 @@ __all__ = [
     "decompress_tree",
     "compressed_paths", "CompressReport", "CompressedParams",
     "shard_tree", "tree_sharding_specs", "validate_tree_sharding",
-    "spec_for_path",
+    "spec_for_path", "stack_streams",
     "AutoBitsConfig", "AutoBitsPlan", "measure_sensitivity",
     "plan_auto_bits", "plan_draft_bits", "plan_to_meta", "plan_from_meta",
 ]

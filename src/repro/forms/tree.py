@@ -15,6 +15,15 @@ The compressed tree is a first-class pytree: it jits, scans, shards and
 checkpoints like the dense tree it replaces (``checkpoint/manager`` stores
 the uint8 magnitudes verbatim), and ``models/layers.linear`` consumes its
 leaves through the polarized-matmul kernel on the serving hot path.
+
+A group of scan-stacked layers may come as a *layer stream*: a list of
+zero-argument callables, the i-th making layer i's float tree (the group's
+leaves without their leading layer axis).  ``compress_tree`` makes,
+compresses and frees one layer at a time and writes its leaves into the
+stacked compressed leaves, so a model whose float32 tree does not fit
+next to its codes is set up holding one layer's float weights at most; the
+codes equal those of compressing the stacked leaf.  :func:`stack_streams`
+stacks streams as they are (no compression).
 """
 from __future__ import annotations
 
@@ -75,6 +84,39 @@ class CompressReport:
 
 def _is_forms_leaf(x) -> bool:
     return isinstance(x, FormsLinearParams)
+
+
+def is_layer_stream(x) -> bool:
+    """A list of zero-argument callables, each making one layer's tree."""
+    return isinstance(x, list) and bool(x) and all(callable(f) for f in x)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_layer(stack: Any, layer: Any, i: jax.Array) -> Any:
+    return jax.tree_util.tree_map(lambda s, x: s.at[i].set(x), stack, layer)
+
+
+def _stack_stream(stream, convert: Callable[[int, Any], Any]) -> Any:
+    """The stack of a layer stream's layers: layer i is made, passed through
+    ``convert(i, tree)`` and written into row i of the stacked leaves (made
+    at the first layer, updated in place), then dropped."""
+    stack = None
+    for i, make in enumerate(stream):
+        layer = convert(i, make())
+        if stack is None:
+            stack = jax.tree_util.tree_map(
+                lambda x: jnp.zeros((len(stream),) + x.shape, x.dtype), layer)
+        stack = _put_layer(stack, layer, jnp.int32(i))
+        del layer
+    return stack
+
+
+def stack_streams(params: Any) -> Any:
+    """``params`` with every layer stream replaced by its stacked layers."""
+    flat, treedef = jax.tree_util.tree_flatten(params, is_leaf=is_layer_stream)
+    return jax.tree_util.tree_unflatten(
+        treedef, [_stack_stream(x, lambda i, t: t) if is_layer_stream(x)
+                  else x for x in flat])
 
 
 # rank-4 leaves with these final path segments are scan-stacked expert
@@ -172,6 +214,8 @@ def compress_tree(
     leaves pass through untouched.  Already-compressed leaves are left alone,
     so the function is idempotent.  ``predicate(path, shape)`` selects the
     leaves to compress (default: the shared crossbar-weight heuristic).
+    A layer stream (module docstring) becomes its stacked layers, made and
+    compressed one layer at a time.
 
     ``plan`` (a ``{path: FormsSpec}`` map, e.g. from
     ``forms.autobits.plan_auto_bits``) overrides the spec per leaf — the
@@ -192,36 +236,62 @@ def compress_tree(
     whole tree.
     """
     flat, treedef = jax.tree_util.tree_flatten_with_path(
-        params, is_leaf=_is_forms_leaf)
+        params, is_leaf=lambda x: _is_forms_leaf(x) or is_layer_stream(x))
     report = CompressReport(errors={})
-    new_leaves = []
     compressed: Dict[str, Any] = {}
-    for path, leaf in flat:
-        pstr = _path_str(path)
+
+    def place(pstr: str, fp: FormsLinearParams) -> FormsLinearParams:
+        if ctx is not None:
+            fp = _place_forms_leaf(pstr, fp, ctx)
+            report.shardings[pstr] = str(fp.mags.sharding.spec)
+        compressed[pstr] = fp
+        return fp
+
+    def convert(pstr: str, leaf: Any, lead: Tuple[int, ...] = (),
+                first: bool = True) -> Any:
+        """One leaf compressed (or passed through) and accounted.  A layer
+        of a stream passes its stack's leading axis as ``lead``, so the
+        predicate sees the stacked shape; its error is the largest over the
+        layers, it counts once (``first``) and is placed once stacked."""
         if (_is_forms_leaf(leaf) or not hasattr(leaf, "ndim")
-                or not predicate(pstr, tuple(leaf.shape))):
+                or not predicate(pstr, lead + tuple(leaf.shape))):
             if _is_forms_leaf(leaf):
                 # idempotent pass-through still counts toward plan coverage
                 compressed[pstr] = leaf
                 report.bits[pstr] = leaf.bits
-            elif hasattr(leaf, "ndim"):
+            elif hasattr(leaf, "ndim") and first:
                 report.num_skipped += 1
-            new_leaves.append(leaf)
-            continue
+            return leaf
         leaf_spec = spec_for_path(plan, pstr, spec)
         fp, err = _compress_leaf(leaf, pstr.rsplit("/", 1)[-1], leaf_spec)
-        if ctx is not None:
-            fp = _place_forms_leaf(pstr, fp, ctx)
-            report.shardings[pstr] = str(fp.mags.sharding.spec)
-        report.errors[pstr] = float(err)
+        report.errors[pstr] = max(float(err), report.errors.get(pstr, 0.0))
         report.bits[pstr] = leaf_spec.bits
-        report.num_compressed += 1
+        report.num_compressed += int(first)
         report.bytes_dense += leaf.size * leaf.dtype.itemsize
         report.bytes_compressed += (fp.mags.size * fp.mags.dtype.itemsize
                                     + fp.signs.size * fp.signs.dtype.itemsize
                                     + fp.scale.size * fp.scale.dtype.itemsize)
-        compressed[pstr] = fp
-        new_leaves.append(fp)
+        return fp if lead else place(pstr, fp)
+
+    def convert_layer(pstr: str, n: int, i: int, layer: Any) -> Any:
+        lflat, ltree = jax.tree_util.tree_flatten_with_path(layer)
+        return jax.tree_util.tree_unflatten(ltree, [
+            convert(f"{pstr}/{_path_str(p)}", x, (n,), i == 0)
+            for p, x in lflat])
+
+    new_leaves = []
+    for path, leaf in flat:
+        pstr = _path_str(path)
+        if not is_layer_stream(leaf):
+            new_leaves.append(convert(pstr, leaf))
+            continue
+        stack = _stack_stream(leaf, functools.partial(convert_layer, pstr,
+                                                      len(leaf)))
+        sflat, stree = jax.tree_util.tree_flatten_with_path(
+            stack, is_leaf=_is_forms_leaf)
+        new_leaves.append(jax.tree_util.tree_unflatten(stree, [
+            place(f"{pstr}/{_path_str(p)}", x) if _is_forms_leaf(x) else x
+            for p, x in sflat]))
     if plan:
         _check_plan_covered(plan, compressed)
     return jax.tree_util.tree_unflatten(treedef, new_leaves), report

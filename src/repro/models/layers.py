@@ -14,6 +14,7 @@ Conventions
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -116,16 +117,52 @@ def rope_freqs(hd: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(hd: int, theta: float, yarn) -> Tuple[jax.Array, float]:
+    """YaRN rotary frequencies of a ``hd``-wide rope (``yarn``: a
+    ``configs.base.YarnConfig``) and the factor on cos/sin, as DeepSeek-V3
+    computes them: frequencies whose wavelength is short against the
+    original context keep theta's, long ones are divided by ``factor``, and
+    those between follow a linear ramp over the dims from the ``beta_fast``
+    to the ``beta_slow`` rotation count."""
+    base = rope_freqs(hd, theta)
+    orig = yarn.original_max_position_embeddings
+
+    def dim_at(rotations):
+        return (hd * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_at(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_at(yarn.beta_slow)), hd - 1)
+    ramp = jnp.clip((jnp.arange(hd // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    freqs = base / yarn.factor * ramp + base * (1.0 - ramp)
+    scale = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return freqs, scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None,
+               mscale: float = 1.0) -> jax.Array:
     """x: (..., S, H, hd) or (..., S, hd); positions: (S,) or (B, S) int.
 
     A (B, S) position grid gives every batch row its own timeline — the
     decode path uses (B, 1) so each serving slot rotates by its own position.
+    ``freqs`` (default theta's) and ``mscale`` (on cos and sin) carry a
+    scaled rope such as :func:`yarn_freqs`.
     """
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     if positions.ndim == 1:
         if x.ndim == 4:   # (B, S, H, hd)
             cos, sin = cos[None, :, None, :], sin[None, :, None, :]
@@ -197,17 +234,20 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      window: Optional[int] = None,
                      q_chunk: int = DEFAULT_Q_CHUNK,
                      positions: Optional[jax.Array] = None,
-                     causal: bool = True) -> jax.Array:
+                     causal: bool = True,
+                     scale: Optional[float] = None) -> jax.Array:
     """Chunked (optionally causal) GQA for train/prefill.
 
     q: (B, S, H, hd); k/v: (B, S, KV, hd).  Scans over ceil(S/q_chunk) query
     chunks with full keys resident — peak scores are (B, H, q_chunk, S).
+    ``scale`` multiplies the scores (default ``hd ** -0.5``).
     """
     b, s, h, hd = q.shape
     kv = k.shape[2]
     hdv = v.shape[-1]   # may differ from hd (MLA: qk dims != v dims)
     g = h // kv
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.int32)
     qr = q.reshape(b, s, kv, g, hd)

@@ -1,54 +1,152 @@
-"""MoE transformer family: olmoe (64e top-8) and deepseek-v3 (MLA + 1 shared +
-256 routed top-8 + MTP).
+"""MoE transformer family: olmoe (64 experts, top-8) and deepseek-v3 (MLA,
+3 leading dense layers, 1 shared + 256 routed top-8 experts under sigmoid
+group-limited routing, MTP).
 
-Expert parallelism: experts are sharded over the ``model`` mesh axis; tokens
-are resharded over *every* mesh axis ("tokens" logical axis) for dispatch.
-Dispatch is capacity-based (position-in-expert via a one-hot cumsum, scatter
-into an ``(E*C, d)`` buffer, batched expert matmuls, gather-combine) — the
-standard dropping MoE of TPU stacks; overflow tokens are dropped at
-``capacity_factor`` (aux loss keeps the router balanced).
+Routing (:func:`route`, float32): softmax top-k (olmoe), or DeepSeek-V3's
+``noaux_tc`` — sigmoid scores, a selection-only correction bias, the top
+``topk_group`` of ``n_group`` expert groups (a group scores the sum of its
+top two), the top-k experts among those, weighted by their un-biased
+scores normalized over the k and times ``routed_scaling_factor``.
+
+Two expert paths share the router:
+
+* serving (bulk and chunked prefill, decode, speculative verify) —
+  :func:`held_moe_ffn`, dropless.  A layer holds experts ``expert_first``
+  .. ``expert_first + experts_held - 1`` of the router's ``num_experts``
+  (one chip's share under expert parallelism; all of them by default).
+  Each held expert runs over every row through the polarized kernel on its
+  compressed codes, weighted by its gate (zero where the row did not route
+  to it), and the shared expert is added.  No row's output depends on the
+  others in its batch, so prefill pads to buckets like the dense family.
+* training (``forward``) — :func:`moe_ffn`, capacity-based dispatch
+  (position-in-expert via a sort, scatter into an ``(E*C, d)`` buffer,
+  batched expert matmuls, gather-combine), over a mesh the shard_map
+  all_to_all of distributed/moe_dispatch.py; tokens past
+  ``capacity_factor`` drop (the aux loss keeps the router balanced).
+
+Leading dense layers (``first_k_dense_replace``) are a second stacked
+group, ``dense_blocks`` (attention + a SwiGLU MLP of width ``d_ff``),
+scanned before the MoE ``blocks``; one cache covers all ``num_layers``
+layers, the dense ones first.
 
 MLA (deepseek): train/prefill use the expanded form; decode uses the
 *absorbed* form (q absorbed through kv_up so attention runs in the latent
 space) with a cache of compressed latents ``c_kv`` + shared rope key — the
-memory-efficient decode that makes 128-batch 32k-decode fit.
+memory-efficient decode that makes 128-batch 32k-decode fit.  With
+``rope_scaling`` the rope part follows YaRN and the softmax scale carries
+its ``mscale_all_dim`` factor squared.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MLAConfig, ModelConfig
 from repro.distributed.sharding import constrain
+from repro.kernels import ops
 from repro.models import layers as L
 from repro.serving import kv_cache as KV
+from repro.serving import trace as T
 
 Params = Dict[str, Any]
+EXPERTS = ("w_gate", "w_up", "w_down")
 
 
 # ---------------------------------------------------------------------------
-# capacity-based MoE FFN
+# routing and the two expert paths
 # ---------------------------------------------------------------------------
 
 def moe_ffn_init(key, cfg: ModelConfig) -> Params:
-    e, d, f = cfg.num_experts, cfg.d_model, cfg.moe_d_ff
+    e, d, f = cfg.held_experts(), cfg.d_model, cfg.moe_d_ff
     ks = jax.random.split(key, 7)
     scale = 1.0 / jnp.sqrt(d)
     p = {
-        "router": L.dense_init(ks[0], d, e, scale=0.02),
+        "router": L.dense_init(ks[0], d, cfg.num_experts, scale=0.02),
         "w_gate": jax.random.normal(ks[1], (e, d, f)) * scale,
         "w_up": jax.random.normal(ks[2], (e, d, f)) * scale,
         "w_down": jax.random.normal(ks[3], (e, f, d)) * (1.0 / jnp.sqrt(f)),
     }
+    if cfg.router_bias:
+        p["router_bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
     if cfg.num_shared_experts:
         fs = cfg.moe_d_ff * cfg.num_shared_experts
         p["shared_gate"] = L.dense_init(ks[4], d, fs)
         p["shared_up"] = L.dense_init(ks[5], d, fs)
         p["shared_down"] = L.dense_init(ks[6], fs, d)
     return p
+
+
+def route(p: Params, xt: jax.Array, cfg: ModelConfig
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router of ``(T, d)`` rows over all ``num_experts``, in float32:
+    ``(weights (T, k), expert ids (T, k), scores (T, E))``."""
+    with jax.named_scope("router"):
+        logits = jnp.matmul(xt.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        if cfg.router_scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+        choice = scores + p["router_bias"] if "router_bias" in p else scores
+        if cfg.n_group > 1:
+            t, e = choice.shape
+            size = e // cfg.n_group
+            group = jax.lax.top_k(choice.reshape(t, cfg.n_group, size),
+                                  2)[0].sum(-1)
+            _, kept = jax.lax.top_k(group, cfg.topk_group)
+            keep = jnp.any(kept[:, :, None] == jnp.arange(cfg.n_group),
+                           axis=1)
+            choice = jnp.where(jnp.repeat(keep, size, axis=-1), choice,
+                               -jnp.inf)
+        _, idx = jax.lax.top_k(choice, cfg.experts_per_token)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if cfg.norm_topk_prob:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        return w * cfg.routed_scaling_factor, idx, scores
+
+
+def _shared_expert(p: Params, xt: jax.Array, dtype) -> jax.Array:
+    with jax.named_scope("shared_expert"):
+        h = jax.nn.silu(L.linear(p, "shared_gate", xt, dtype))
+        h = h * L.linear(p, "shared_up", xt, dtype)
+        return L.linear(p, "shared_down", h, dtype)
+
+
+def held_moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig, dtype
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The serving expert layer, dropless: x (B, S, d) -> (out, counts).
+
+    Only the held experts are computed, each over every row, weighted by
+    its gate (zero for rows that did not route to it).  ``counts``: the
+    (row, held expert) pairs routed here (``moe.routed``), the rows the held
+    experts computed (``moe.rows``) and rows x top-k (``moe.routes``)."""
+    b, s, d = x.shape
+    t = b * s
+    xt = x.reshape(t, d).astype(dtype)
+    w, idx, _ = route(p, xt, cfg)
+    n = cfg.held_experts()
+    with jax.named_scope("experts"):
+        hit = idx[:, :, None] == cfg.expert_first + jnp.arange(n)
+        gates = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)  # (T, n)
+
+        def expert(acc, inp):
+            ep, gate = inp
+            h = jax.nn.silu(L.linear(ep, "w_gate", xt, dtype))
+            h = h * L.linear(ep, "w_up", xt, dtype)
+            y = L.linear(ep, "w_down", h, dtype).astype(jnp.float32)
+            return acc + gate[:, None] * y, None
+
+        y, _ = jax.lax.scan(expert, jnp.zeros((t, d), jnp.float32),
+                            ({k: p[k] for k in EXPERTS}, gates.T))
+    y = y.astype(dtype)
+    if cfg.num_shared_experts:
+        y = y + _shared_expert(p, xt, dtype)
+    counts = {"moe.routed": jnp.sum(hit), "moe.rows": jnp.int32(n * t),
+              "moe.routes": jnp.int32(t * cfg.experts_per_token)}
+    return y.reshape(b, s, d), counts
 
 
 def _expert_ffn(p: Params, bufe: jax.Array, dtype) -> jax.Array:
@@ -62,31 +160,33 @@ def _expert_ffn(p: Params, bufe: jax.Array, dtype) -> jax.Array:
 
 def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig, dtype
             ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out, aux_loss).
+    """The training expert layer, capacity-based: x (B, S, d) -> (out,
+    aux_loss).
 
     Two dispatch paths: the shard_map all_to_all expert-parallel path (large
     token counts on a mesh — production) and a small pjit scatter path
-    (single-device tests, decode-sized token counts).
+    (single-device tests).  Dispatch needs every expert's weights.
     """
     from repro.distributed import moe_dispatch
     from repro.distributed.sharding import current_context
 
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.experts_per_token
+    if cfg.held_experts() != e:
+        raise ValueError(f"capacity dispatch needs all {e} experts; this "
+                         f"layer holds {cfg.held_experts()} (a serving "
+                         f"share: held_moe_ffn serves it)")
     t = b * s
 
     xt = x.reshape(t, d)
     xt = constrain(xt, "tokens", None)
-    logits = L.linear(p, "router", xt, dtype).astype(jnp.float32)  # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)                            # (T, K)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, idx, scores = route(p, xt, cfg)
 
     # aux load-balance loss (switch-style)
+    probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
     density = jnp.mean(jax.nn.one_hot(idx[:, 0], e, dtype=jnp.float32), axis=0)
     prob_mean = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(density * prob_mean)
-
     ctx = current_context()
     if moe_dispatch.can_use(ctx, t, e):
         n_dev = ctx.axis_size("tokens")
@@ -117,9 +217,7 @@ def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig, dtype
         y = y_tk.reshape(t, k, d).sum(axis=1)
 
     if cfg.num_shared_experts:
-        hs = jax.nn.silu(L.linear(p, "shared_gate", xt.astype(dtype), dtype))
-        hs = hs * L.linear(p, "shared_up", xt.astype(dtype), dtype)
-        y = y + L.linear(p, "shared_down", hs, dtype)
+        y = y + _shared_expert(p, xt.astype(dtype), dtype)
 
     y = constrain(y, "tokens", None)
     return constrain(y.reshape(b, s, d), "batch", "model", None), aux
@@ -146,22 +244,40 @@ def mla_init(key, cfg: ModelConfig) -> Params:
     }
 
 
+def _mla_rope(cfg: ModelConfig) -> Tuple[Any, float, float]:
+    """(rope frequencies or None for theta's, factor on cos/sin, softmax
+    scale) of MLA's rope part: YaRN under ``rope_scaling``, where the
+    softmax scale ``(qk_nope + qk_rope) ** -0.5`` also takes
+    ``yarn_mscale(factor, mscale_all_dim) ** 2``."""
+    m: MLAConfig = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    yarn = cfg.rope_scaling
+    if yarn is None:
+        return None, 1.0, scale
+    freqs, mscale = L.yarn_freqs(m.qk_rope_head_dim, cfg.rope_theta, yarn)
+    if yarn.mscale_all_dim:
+        scale *= L.yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+    return freqs, mscale, scale
+
+
 def _mla_qkv_full(p: Params, x, cfg: ModelConfig, positions, dtype):
     """Expanded-form q, k, v for full-sequence attention."""
     m: MLAConfig = cfg.mla
     b, s, _ = x.shape
     h = cfg.num_heads
     qk_rope, qk_nope, dv = m.qk_rope_head_dim, m.qk_nope_head_dim, m.v_head_dim
+    freqs, mscale, _ = _mla_rope(cfg)
 
     x = constrain(x, "batch", None, None)   # Megatron-SP gather
     cq = L.rmsnorm(L.linear(p, "q_down", x, dtype), p["q_norm"], cfg.norm_eps)
     q = L.linear(p, "q_up", cq, dtype).reshape(b, s, h, qk_nope + qk_rope)
     q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta, freqs, mscale)
 
     kv = L.linear(p, "kv_down", x, dtype)
     c_kv = L.rmsnorm(kv[..., : m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_rope = L.apply_rope(kv[..., m.kv_lora_rank:], positions, cfg.rope_theta)
+    k_rope = L.apply_rope(kv[..., m.kv_lora_rank:], positions, cfg.rope_theta,
+                          freqs, mscale)
 
     kvu = L.linear(p, "kv_up", c_kv, dtype).reshape(b, s, h, qk_nope + dv)
     k_nope, v = kvu[..., :qk_nope], kvu[..., qk_nope:]
@@ -178,7 +294,8 @@ def mla_full(p: Params, x, cfg: ModelConfig, positions, dtype,
     per-position decode-cache entries ({"c_kv", "k_rope"}) so bulk prefill
     can commit them in one write."""
     q, k, v, c_kv, k_rope = _mla_qkv_full(p, x, cfg, positions, dtype)
-    out = L.causal_attention(q, k, v, q_chunk=q_chunk, positions=positions)
+    out = L.causal_attention(q, k, v, q_chunk=q_chunk, positions=positions,
+                             scale=_mla_rope(cfg)[2])
     b, s = x.shape[:2]
     out = constrain(L.linear(p, "wo", out.reshape(b, s, -1), dtype),
                     "batch", "model", None)
@@ -200,14 +317,16 @@ def mla_decode(p: Params, x, cfg: ModelConfig, cache: Dict[str, jax.Array],
     qk_rope, qk_nope, dv, r = (m.qk_rope_head_dim, m.qk_nope_head_dim,
                                m.v_head_dim, m.kv_lora_rank)
     positions = L.position_grid(pos, b, s)                # (B, T)
+    freqs, mscale, scale = _mla_rope(cfg)
 
     cq = L.rmsnorm(L.linear(p, "q_down", x, dtype), p["q_norm"], cfg.norm_eps)
     q = L.linear(p, "q_up", cq, dtype).reshape(b, s, h, qk_nope + qk_rope)
     q_nope, q_rope = q[..., :qk_nope], q[..., qk_nope:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta, freqs, mscale)
     kv = L.linear(p, "kv_down", x, dtype)
     c_new = L.rmsnorm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
-    k_rope_new = L.apply_rope(kv[..., r:], positions, cfg.rope_theta)
+    k_rope_new = L.apply_rope(kv[..., r:], positions, cfg.rope_theta, freqs,
+                              mscale)
 
     # transient updated views for attention; only the new-token latents are
     # returned (the caller commits the token columns after the layer scan)
@@ -222,16 +341,26 @@ def mla_decode(p: Params, x, cfg: ModelConfig, cache: Dict[str, jax.Array],
     w_uk = kv_up.reshape(r, h, qk_nope + dv)[..., :qk_nope]
     w_uv = kv_up.reshape(r, h, qk_nope + dv)[..., qk_nope:]
     q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, w_uk)
-    scale = 1.0 / jnp.sqrt(qk_nope + qk_rope).astype(jnp.float32)
-    scores = (jnp.einsum("bthr,bsr->bhts", q_lat.astype(c_cache.dtype),
-                         c_cache, preferred_element_type=jnp.float32)
-              + jnp.einsum("bthp,bsp->bhts", q_rope.astype(r_cache.dtype),
-                           r_cache, preferred_element_type=jnp.float32)) * scale
+    # scores and the latent sum take operands in the cache's dtype and
+    # accumulate in float32; the CPU's dot has no bf16 x bf16 -> f32 form,
+    # so there the operands are widened after rounding (the same products
+    # and sums)
+    wide = c_cache.dtype if ops.on_tpu() else jnp.float32
+
+    def operand(a):
+        return a.astype(c_cache.dtype).astype(wide)
+
+    c_op = operand(c_cache)
+    scores = (jnp.einsum("bthr,bsr->bhts", operand(q_lat), c_op,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bthp,bsp->bhts", operand(q_rope),
+                           operand(r_cache),
+                           preferred_element_type=jnp.float32)) * scale
     kpos = jnp.arange(c_cache.shape[1], dtype=jnp.int32)
     mask = kpos[None, None, :] <= positions[:, :, None]    # (B, T, S)
     scores = jnp.where(mask[:, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bhts,bsr->bthr", probs.astype(c_cache.dtype), c_cache,
+    o_lat = jnp.einsum("bhts,bsr->bthr", operand(probs), c_op,
                        preferred_element_type=jnp.float32).astype(dtype)
     o = jnp.einsum("bthr,rhv->bthv", o_lat, w_uv)
     out = L.linear(p, "wo", o.reshape(b, s, h * dv), dtype)
@@ -243,13 +372,18 @@ def mla_decode(p: Params, x, cfg: ModelConfig, cache: Dict[str, jax.Array],
 # blocks / model
 # ---------------------------------------------------------------------------
 
-def _block_init(key, cfg: ModelConfig) -> Params:
+def _block_init(key, cfg: ModelConfig, dense: bool = False) -> Params:
+    """One block: attention (MLA or GQA) and either the expert layer or,
+    for a leading dense layer, a SwiGLU MLP of width ``d_ff``."""
     k1, k2 = jax.random.split(key)
     p: Params = {
         "norm1": L.rmsnorm_init(cfg.d_model),
         "norm2": L.rmsnorm_init(cfg.d_model),
-        "moe": moe_ffn_init(k2, cfg),
     }
+    if dense:
+        p["mlp"] = L.swiglu_init(k2, cfg.d_model, cfg.d_ff)
+    else:
+        p["moe"] = moe_ffn_init(k2, cfg)
     if cfg.mla is not None:
         p["mla"] = mla_init(k1, cfg)
     else:
@@ -260,14 +394,19 @@ def _block_init(key, cfg: ModelConfig) -> Params:
 
 def init(cfg: ModelConfig, key) -> Params:
     ke, kb, kh, km = jax.random.split(key, 4)
-    block_keys = jax.random.split(kb, cfg.num_layers)
-    blocks = jax.vmap(lambda k: _block_init(k, cfg))(block_keys)
+    k = cfg.first_k_dense_replace
+    block_keys = jax.random.split(kb, cfg.num_layers - k)
+    blocks = jax.vmap(lambda kk: _block_init(kk, cfg))(block_keys)
     params: Params = {
         "embed": L.embed_init(ke, cfg.vocab_size, cfg.d_model),
         "blocks": blocks,
         "final_norm": L.rmsnorm_init(cfg.d_model),
         "head": L.dense_init(kh, cfg.d_model, cfg.vocab_size, scale=0.02),
     }
+    if k:
+        dense_keys = jax.random.split(jax.random.fold_in(kb, 1), k)
+        params["dense_blocks"] = jax.vmap(
+            lambda kk: _block_init(kk, cfg, dense=True))(dense_keys)
     if cfg.mtp:
         params["mtp"] = {"proj": L.dense_init(km, 2 * cfg.d_model, cfg.d_model),
                          "block": _block_init(km, cfg),
@@ -276,28 +415,71 @@ def init(cfg: ModelConfig, key) -> Params:
 
 
 def _block_apply(cfg: ModelConfig, bp: Params, x, positions, cache, pos,
-                 dtype, q_chunk: int, collect_kv: bool = False):
-    xa = L.rmsnorm(x, bp["norm1"], cfg.norm_eps)
+                 dtype, q_chunk: int, collect_kv: bool = False,
+                 serve: bool = False):
+    """One block -> (x, aux loss, new cache rows or None, device counts).
+    ``serve`` picks the dropless held-expert layer over the training
+    dispatch."""
     new_cache = None
-    if cfg.mla is not None:
-        if cache is None:
-            h, latents = mla_full(bp["mla"], xa, cfg, positions, dtype, q_chunk)
-            if collect_kv:
-                new_cache = latents
+    with jax.named_scope("attention"):
+        xa = L.rmsnorm(x, bp["norm1"], cfg.norm_eps)
+        if cfg.mla is not None:
+            if cache is None:
+                h, latents = mla_full(bp["mla"], xa, cfg, positions, dtype,
+                                      q_chunk)
+                if collect_kv:
+                    new_cache = latents
+            else:
+                h, new_cache = mla_decode(bp["mla"], xa, cfg, cache, pos,
+                                          dtype)
         else:
-            h, new_cache = mla_decode(bp["mla"], xa, cfg, cache, pos, dtype)
-    else:
-        kv_cache = (cache["k"], cache["v"]) if cache is not None else None
-        h, new_cache = L.attention_block(
-            bp["attn"], xa, n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
-            hd=cfg.hd(), rope_theta=cfg.rope_theta, positions=positions,
-            q_chunk=q_chunk, cache=kv_cache, cache_pos=pos,
-            return_kv=collect_kv, dtype=dtype)
-        if new_cache is not None:
-            new_cache = {"k": new_cache[0], "v": new_cache[1]}
-    x = x + h
-    y, aux = moe_ffn(bp["moe"], L.rmsnorm(x, bp["norm2"], cfg.norm_eps), cfg, dtype)
-    return x + y, aux, new_cache
+            kv_cache = (cache["k"], cache["v"]) if cache is not None else None
+            h, new_cache = L.attention_block(
+                bp["attn"], xa, n_heads=cfg.num_heads, n_kv=cfg.num_kv_heads,
+                hd=cfg.hd(), rope_theta=cfg.rope_theta, positions=positions,
+                q_chunk=q_chunk, cache=kv_cache, cache_pos=pos,
+                return_kv=collect_kv, dtype=dtype)
+            if new_cache is not None:
+                new_cache = {"k": new_cache[0], "v": new_cache[1]}
+        x = x + h
+    aux, counts = jnp.zeros((), jnp.float32), {}
+    with jax.named_scope("mlp"):
+        xm = L.rmsnorm(x, bp["norm2"], cfg.norm_eps)
+        if "mlp" in bp:
+            y = L.swiglu(bp["mlp"], xm, dtype)
+        elif serve:
+            y, counts = held_moe_ffn(bp["moe"], xm, cfg, dtype)
+        else:
+            y, aux = moe_ffn(bp["moe"], xm, cfg, dtype)
+    return x + y, aux, new_cache, counts
+
+
+def _groups(cfg: ModelConfig, params: Params) -> List[Tuple[Params, int, int]]:
+    """The stacked layer groups in order, each with its first layer and the
+    layer past its last: the leading dense layers, then the MoE layers."""
+    k = cfg.first_k_dense_replace
+    lead = [(params["dense_blocks"], 0, k)] if k else []
+    return lead + [(params["blocks"], k, cfg.num_layers)]
+
+
+def _scan_layers(cfg: ModelConfig, params: Params, x, layer, xs=None):
+    """Scan ``layer(x, (block params, per-layer input)) -> (x, (rows,
+    counts))`` over every group in order, ``xs`` (a dict of ``(L, ...)``
+    leaves, or None) split by layer; returns (x, rows concatenated over the
+    layers).  The groups' device counts are added up and reported."""
+    rows, counts = [], {}
+    with jax.named_scope("layers"):
+        for group, lo, hi in _groups(cfg, params):
+            part = (None if xs is None
+                    else {n: v[lo:hi] for n, v in xs.items()})
+            x, (r, c) = jax.lax.scan(layer, x, (group, part))
+            rows.append(r)
+            for name, v in c.items():
+                counts[name] = counts.get(name, 0) + jnp.sum(v)
+    for name, v in counts.items():
+        T.add_device_count(name, v)
+    return x, {n: jnp.concatenate([r[n] for r in rows], axis=0)
+               for n in rows[0]}
 
 
 def head_matrix(cfg: ModelConfig, params: Params) -> jax.Array:
@@ -314,22 +496,27 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array], *,
     positions = jnp.arange(s, dtype=jnp.int32)
 
     def body(x, bp):
-        out, aux, _ = _block_apply(cfg, bp, x, positions, None, None, dtype, q_chunk)
+        out, aux, _, _ = _block_apply(cfg, bp, x, positions, None, None, dtype,
+                                      q_chunk)
         return out, aux
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
-    x, auxs = jax.lax.scan(body, x, params["blocks"])
+    auxs = []
+    for group, _, _ in _groups(cfg, params):
+        x, a = jax.lax.scan(body, x, group)
+        auxs.append(a)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    aux: Dict[str, jax.Array] = {"moe_aux_loss": jnp.mean(auxs)}
+    # the balance loss of the expert layers (the dense ones have none)
+    aux: Dict[str, jax.Array] = {"moe_aux_loss": jnp.mean(auxs[-1])}
 
     if cfg.mtp and "mtp" in params:
         # multi-token prediction: combine h_t with emb(token_{t+1}) -> predict t+2
         emb_next = jnp.roll(L.embed_lookup(params["embed"], batch["tokens"], dtype),
                             -1, axis=1)
         hm = L.linear(params["mtp"], "proj", jnp.concatenate([x, emb_next], axis=-1), dtype)
-        hm, mtp_aux, _ = _block_apply(cfg, params["mtp"]["block"], hm, positions,
-                                      None, None, dtype, q_chunk)
+        hm, mtp_aux, _, _ = _block_apply(cfg, params["mtp"]["block"], hm,
+                                         positions, None, None, dtype, q_chunk)
         hm = L.rmsnorm(hm, params["mtp"]["norm"], cfg.norm_eps)
         aux["moe_aux_loss"] = aux["moe_aux_loss"] + mtp_aux / max(cfg.num_layers, 1)
         if return_hidden:
@@ -378,16 +565,11 @@ def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
 def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
             cache: Dict[str, jax.Array], slot: jax.Array, length: jax.Array
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Bulk prefill of one serving slot (tokens: (1, S)): expanded-form
-    attention, then one cache write per leaf — MLA latents or GQA K/V,
-    whichever this config caches.
-
-    Served UNPADDED (``registry.Model.padded_prefill`` is False for moe):
-    pad tokens would enter the capacity-based expert dispatch and steal
-    capacity from real tokens.  Note prefill routes the whole prompt in one
-    batch while decode routes ``batch_slots`` tokens per step, so capacity
-    drops can differ between the two paths — inherent to dropping MoE (the
-    aux loss keeps the router balanced enough that drops are rare)."""
+    """Bulk prefill of one serving slot (tokens: (1, S), right-padded past
+    ``length``): expanded-form attention, then one cache write per leaf —
+    MLA latents or GQA K/V, whichever this config caches.  Pad rows route
+    too, but the serving expert layer drops nothing, so they change no real
+    row (``registry.Model.padded_prefill``)."""
     logits, rows = _prefill_core(cfg, params, tokens, length)
     zero = jnp.zeros((), jnp.int32)
     slot = jnp.asarray(slot, jnp.int32)
@@ -408,12 +590,14 @@ def _prefill_core(cfg: ModelConfig, params: Params, tokens: jax.Array,
     s = x.shape[1]
     positions = jnp.arange(s, dtype=jnp.int32)
 
-    def body(x, bp):
-        out, _aux, kv = _block_apply(cfg, bp, x, positions, None, None, dtype,
-                                     L.DEFAULT_Q_CHUNK, collect_kv=True)
-        return out, kv
+    def body(x, xs):
+        bp, _ = xs
+        out, _aux, kv, counts = _block_apply(
+            cfg, bp, x, positions, None, None, dtype, L.DEFAULT_Q_CHUNK,
+            collect_kv=True, serve=True)
+        return out, (kv, counts)
 
-    x, kvs = jax.lax.scan(body, x, params["blocks"])
+    x, kvs = _scan_layers(cfg, params, x, body)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     x_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
     logits = L.lm_logits(x_last, params["head"], dtype)
@@ -423,8 +607,8 @@ def _prefill_core(cfg: ModelConfig, params: Params, tokens: jax.Array,
 def prefill_paged(cfg: ModelConfig, params: Params, tokens: jax.Array,
                   cache: KV.PagedKVCache, pages: jax.Array, slot: jax.Array,
                   length: jax.Array) -> Tuple[jax.Array, KV.PagedKVCache]:
-    """Paged bulk prefill: same compute as :func:`prefill` (exact-length,
-    unpadded tokens), committed as whole-page scatters at ``pages``."""
+    """Paged bulk prefill: same compute as :func:`prefill`, committed as
+    whole-page scatters at ``pages``."""
     del slot
     logits, rows = _prefill_core(cfg, params, tokens, length)
     return logits, KV.commit_pages(cache, rows, pages)
@@ -435,12 +619,9 @@ def _decode_core(cfg: ModelConfig, params: Params, tokens: jax.Array,
     """Shared decode compute against (L, B, S, ...) cache views (persistent
     dense leaves or block-table gathers).  tokens: (B, T) with token t of
     row b at position ``pos[b] + t``.  Returns (logits (B, T, V), per-leaf
-    new-token rows (L, B, T, ...)).
-
-    Note multi-token verification (T > 1) routes B*T tokens through the
-    capacity-based expert dispatch per step instead of B — like prefill vs
-    decode, capacity drops can differ between T=1 and T>1 at tight
-    ``capacity_factor`` (inherent to dropping MoE)."""
+    new-token rows (L, B, T, ...)).  The expert layer is the dropless
+    serving one, so a multi-token verify (T > 1) routes each token as a
+    single-token step would."""
     dtype = jnp.dtype(cfg.dtype)
     b, t = tokens.shape
     x = L.embed_lookup(params["embed"], tokens, dtype)
@@ -448,12 +629,12 @@ def _decode_core(cfg: ModelConfig, params: Params, tokens: jax.Array,
 
     def body(x, xs):
         bp, layer_cache = xs
-        out, _aux, new_cache = _block_apply(cfg, bp, x, positions, layer_cache,
-                                            positions, dtype,
-                                            L.DEFAULT_Q_CHUNK)
-        return out, new_cache
+        out, _aux, new_cache, counts = _block_apply(
+            cfg, bp, x, positions, layer_cache, positions, dtype,
+            L.DEFAULT_Q_CHUNK, serve=True)
+        return out, (new_cache, counts)
 
-    x, tok_cache = jax.lax.scan(body, x, (params["blocks"], views))
+    x, tok_cache = _scan_layers(cfg, params, x, body, views)
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = L.lm_logits(x, params["head"], dtype)
     return logits, tok_cache

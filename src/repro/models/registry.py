@@ -127,9 +127,9 @@ def build(cfg: ModelConfig) -> Model:
             cfg, params, tokens, cache, slot, length),
         head_matrix=lambda params: mod.head_matrix(cfg, params),
         input_fields=fields,
-        # moe is exact-length too: padded tokens would route through the
-        # capacity-based dispatch and steal expert capacity from real tokens
-        padded_prefill=cfg.family not in ("xlstm", "zamba", "moe"),
+        # recurrent state consumes every token: exact-length prefill there;
+        # moe pads like dense (its serving expert layer drops no token)
+        padded_prefill=cfg.family not in ("xlstm", "zamba"),
         paged_in_place=cfg.family == "dense",
         **paged_kw,
     )

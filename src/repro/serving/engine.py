@@ -8,9 +8,10 @@ round-trips:
 
 * **Bulk prefill** — admitting an L-token prompt costs ONE jitted
   ``model.prefill`` call (chunked full-sequence attention + a one-shot cache
-  write), not L decode steps.  Attention families pad prompts to
-  power-of-two buckets to bound recompilation; recurrent families
-  (``Model.padded_prefill == False``) compile per exact length.
+  write), not L decode steps.  Attention families (MoE included: its serving
+  routing drops no token, so pad rows take nothing from real ones) pad
+  prompts to power-of-two buckets to bound recompilation; recurrent
+  families (``Model.padded_prefill == False``) compile per exact length.
 * **Donated caches** — the KV/state cache is donated into both jitted entry
   points (``donate_argnums``, matching launch/train.py), so cache updates
   alias in place instead of copying the full cache every token.
@@ -41,7 +42,10 @@ fall back to the dense slot-addressed cache.
 With ``forms=True``/``spec=...`` the engine compresses the weights once
 (``repro.forms.compress_tree``) and decodes directly on the compressed
 pytree: uint8 magnitudes + int8 fragment signs through the polarized-matmul
-kernel, no float fake-quant copy.
+kernel, no float fake-quant copy.  A stacked block group may come as a
+layer stream (a list of callables, one per layer; ``repro.forms.tree``):
+each layer is made and compressed in turn, so set-up holds one layer's
+float weights at a time.
 
 With ``speculate=True`` (paged families) the scheduler's decode round is
 self-speculative (serving/speculate.py, DESIGN.md §6e): a low-bit draft
@@ -66,7 +70,7 @@ from repro.distributed.sharding import (ParallelContext, cache_shardings,
                                         parallel_context, params_shardings,
                                         reshard_state)
 from repro.forms import (CompressReport, FormsSpec, compress_tree,
-                         default_spec, sparsity_stats)
+                         default_spec, sparsity_stats, stack_streams)
 from repro.kernels.sparsity import SparsityMeter
 from repro.models.registry import Model
 from repro.serving import kv_cache as KV
@@ -179,15 +183,16 @@ class ModelRunner:
         with default_spec(self.spec), sparsity_stats(self.meter):
             def body(carry, _):
                 tok, cache, pos, key = carry
-                logits, cache = step(p, tok[:, None], cache, pos)
+                with T.device_counts() as counts:
+                    logits, cache = step(p, tok[:, None], cache, pos)
                 lg = logits[:, 0].astype(jnp.float32)
                 key, sub = jax.random.split(key)
                 nxt = _sample_on_device(lg, temps, sub)
-                return (nxt, cache, pos + 1, key), nxt
+                return (nxt, cache, pos + 1, key), (nxt, dict(counts))
 
-            (_, c, _, _), toks_out = jax.lax.scan(
+            (_, c, _, _), (toks_out, counts) = jax.lax.scan(
                 body, (toks, c, pos, key), None, length=self.decode_block)
-        return toks_out, c
+        return toks_out, {n: v.sum() for n, v in counts.items()}, c
 
     @property
     def page_size(self) -> int:
@@ -196,13 +201,15 @@ class ModelRunner:
     def _out_shardings_kw(self) -> Dict[str, Any]:
         """Pin the jitted outputs' shardings on a mesh: the returned cache
         keeps the engine's NamedSharding layout (exact donation aliasing, and
-        ``.sharding`` stays assertable across steps); sampled tokens come
-        back replicated — the host reads them every block anyway."""
+        ``.sharding`` stays assertable across steps); sampled tokens and
+        device counters come back replicated — the host reads them every
+        block anyway."""
         if self.ctx is None:
             return {}
         from jax.sharding import NamedSharding, PartitionSpec
         replicated = NamedSharding(self.ctx.mesh, PartitionSpec())
-        return {"out_shardings": (replicated, self.cache_shardings)}
+        return {"out_shardings": (replicated, replicated,
+                                  self.cache_shardings)}
 
     # ------------------------------------------------------------------
     # prefill
@@ -223,11 +230,11 @@ class ModelRunner:
         """Shared prefill tail: one bulk model call + on-device sampling of
         the first token; ``call`` is the dense or destination-page-bound
         paged prefill."""
-        with default_spec(self.spec):
+        with default_spec(self.spec), T.device_counts() as counts:
             logits, c = call(p, toks, c, slot, length)
         lg = logits.reshape(1, -1).astype(jnp.float32)
         tok = _sample_on_device(lg, temp[None], key)
-        return tok[0], c
+        return tok[0], dict(counts), c
 
     def _get_prefill(self, bucket: int):
         fn = self._prefill_fns.get(bucket)
@@ -284,9 +291,11 @@ class ModelRunner:
                 args.append(jnp.asarray(pages, jnp.int32))
             args += [jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32),
                      jnp.asarray(temperature, jnp.float32), sub]
-        tok, self.cache = self._dispatch("prefill", bucket, fn, args)
+        tok, counts, self.cache = self._dispatch("prefill", bucket, fn, args)
         with self.tracer.span("runner.wait"):
-            return int(tok)
+            tok = int(tok)
+            self._count_device(counts)
+            return tok
 
     def _dispatch(self, program: str, width: int, fn: Any, args: Any) -> Any:
         """Call one jitted program (the ``runner.dispatch`` span), and
@@ -313,6 +322,12 @@ class ModelRunner:
         if first or T.backend_compiles() != seen:
             self.tracer.count(f"runner.compiles.{program}.{width}")
         return out
+
+    def _count_device(self, counts: Dict[str, jax.Array]) -> None:
+        """Add the device counters a program returned (``T.device_counts``)
+        to the tracer's; called once its tokens are on the host."""
+        for name, n in jax.device_get(counts).items():
+            self.tracer.count(name, int(n))
 
     def _count_kv_steps(self, steps: int, t: int,
                         model: Optional[Model] = None) -> None:
@@ -367,13 +382,14 @@ class ModelRunner:
         if fn is None:
             def _chunk_fn(p, c, toks, pos, tables, cols, temps, key):
                 with default_spec(self.spec), sparsity_stats(self.meter):
-                    logits, c = self.model.decode_paged(p, toks, c, pos,
-                                                        tables)
+                    with T.device_counts() as counts:
+                        logits, c = self.model.decode_paged(p, toks, c, pos,
+                                                            tables)
                     lg = jnp.take_along_axis(
                         logits, cols[:, None, None],
                         axis=1)[:, 0].astype(jnp.float32)
                     tok = _sample_on_device(lg, temps, key)
-                return tok, c
+                return tok, dict(counts), c
 
             fn = jax.jit(_chunk_fn,
                          donate_argnums=(1,) if self.donate else (),
@@ -404,10 +420,12 @@ class ModelRunner:
                     jnp.array(block_tables, jnp.int32, copy=True),
                     jnp.array(cols, jnp.int32, copy=True),
                     jnp.array(temps, jnp.float32, copy=True), sub)
-        tok, self.cache = self._dispatch("chunk", width, fn, args)
+        tok, counts, self.cache = self._dispatch("chunk", width, fn, args)
         self._count_kv_steps(1, width)
         with self.tracer.span("runner.wait"):
-            return np.asarray(tok)
+            tok = np.asarray(tok)
+            self._count_device(counts)
+            return tok
 
     # ------------------------------------------------------------------
     # decode
@@ -436,12 +454,14 @@ class ModelRunner:
             if self.paged:
                 args.append(jnp.array(block_tables, jnp.int32, copy=True))
             args += [jnp.array(temps, jnp.float32, copy=True), sub]
-        toks_out, self.cache = self._dispatch("decode", self.decode_block,
-                                              self._decode, args)
+        toks_out, counts, self.cache = self._dispatch(
+            "decode", self.decode_block, self._decode, args)
         if self.paged:
             self._count_kv_steps(self.decode_block, 1)
         with self.tracer.span("runner.wait"):
-            return np.asarray(toks_out)
+            toks_out = np.asarray(toks_out)
+            self._count_device(counts)
+            return toks_out
 
     def decode_round(self, tokens: np.ndarray, positions: np.ndarray,
                      temps: np.ndarray,
@@ -808,9 +828,8 @@ class ServingEngine:
     own weights drafts ``draft_k`` tokens per round and the target verifies
     them in one bounded multi-token forward (paged families only;
     DESIGN.md §6e).  Greedy speculative output is token-identical to plain
-    decoding; dropping-MoE families share bulk prefill's caveat — the
-    verify routes B*(K+1) tokens per step, so identity needs a capacity
-    that drops neither path's tokens.
+    decoding (MoE included: serving routes without drops, so a token's
+    experts do not depend on how many tokens a step carries).
 
     ``plan={path: FormsSpec}`` serves a *heterogeneous* compressed tree:
     per-leaf spec overrides (bit-widths, fragment geometry) resolved by
@@ -885,6 +904,8 @@ class ServingEngine:
             params, self.compression_report = compress_tree(
                 params, self.spec, ctx=self.ctx, plan=plan)
             self.compression_errors = self.compression_report.errors
+        else:
+            params = stack_streams(params)
         self.max_len = max_len
         self.slots = batch_slots
         self.donate = donate

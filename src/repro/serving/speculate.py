@@ -133,6 +133,9 @@ def skip_layers(model: Model, params: Any, layer_step: int
     if layer_step <= 1:
         return model, params
     cfg = model.config
+    if cfg.first_k_dense_replace:
+        raise ValueError("layer skipping keeps one stacked group; this "
+                         "model has leading dense layers in another")
     keep = jnp.asarray(list(range(0, cfg.num_layers, layer_step)))
     out = dict(params)
     for name in ("blocks", "dec_blocks"):

@@ -18,6 +18,12 @@ the runner open spans at their boundaries and count the work they grant:
   stay in memory only.
 * ``count(name, n=1)`` adds to an integer counter, whether on or off.
 
+Device counters: model code adds counts that only the device knows (the
+MoE layer's routed rows) with :func:`add_device_count` while a runner
+program traces inside :func:`device_counts`; the program returns them
+beside its tokens and the runner adds them to the tracer's counters when
+it reads those tokens, so they cost no extra host sync.
+
 The tracer is on after ``enable()`` (``engine.tracer.enable()``) and
 while an in-process profiler session runs (``jax.profiler.start_trace`` or
 ``jax.profiler.trace``): a profile of the serving loop carries the
@@ -29,12 +35,14 @@ counter names are listed in README.md ("Tracing the serving loop").
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import time
 import warnings
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 try:  # the in-process profiler session, if any (JAX keeps it privately)
     from jax._src.profiler import _profile_state as _PROFILE_STATE
@@ -60,6 +68,32 @@ def backend_compiles() -> int:
     """XLA executables this process has compiled or loaded from the
     persistent cache since the first tracer was built."""
     return _backend_compiles
+
+
+_DEVICE_COUNTS: Optional[Dict[str, jax.Array]] = None
+
+
+@contextlib.contextmanager
+def device_counts() -> Iterator[Dict[str, jax.Array]]:
+    """Collect the counts that model code adds with :func:`add_device_count`
+    while a program traces in this context; yields the dict (name -> traced
+    int32 scalar) they are summed into."""
+    global _DEVICE_COUNTS
+    prev, _DEVICE_COUNTS = _DEVICE_COUNTS, {}
+    try:
+        yield _DEVICE_COUNTS
+    finally:
+        _DEVICE_COUNTS = prev
+
+
+def add_device_count(name: str, n: jax.Array) -> None:
+    """Add the traced integer ``n`` to the device counter ``name`` of the
+    program tracing inside :func:`device_counts` (a no-op outside one).
+    Call it outside the scans the counted model call opens: such a scan
+    returns its counts as outputs and the caller adds their sum."""
+    if _DEVICE_COUNTS is not None:
+        n = jnp.asarray(n, jnp.int32)
+        _DEVICE_COUNTS[name] = _DEVICE_COUNTS.get(name, 0) + n
 
 
 def profiler_running() -> bool:

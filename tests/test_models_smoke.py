@@ -86,10 +86,19 @@ def test_full_configs_match_assignment():
     assert (c.num_layers, c.d_model, c.num_heads, c.vocab_size,
             c.num_experts, c.experts_per_token) == (61, 7168, 128, 129280, 256, 8)
     assert c.mla is not None and c.mtp
+    assert (c.d_ff, c.moe_d_ff, c.first_k_dense_replace,
+            c.num_shared_experts, c.norm_eps) == (18432, 2048, 3, 1, 1e-6)
+    assert (c.router_scoring, c.router_bias, c.n_group, c.topk_group,
+            c.norm_topk_prob, c.routed_scaling_factor) == (
+                "sigmoid", True, 8, 4, True, 2.5)
+    assert c.held_experts() == 256
+    assert (c.rope_scaling.factor, c.rope_scaling.mscale_all_dim,
+            c.rope_scaling.original_max_position_embeddings) == (40, 1, 4096)
     c = get_config("qwen2-1.5b")
     assert c.qkv_bias and c.vocab_size == 151936 and c.num_kv_heads == 2
     c = get_config("olmoe-1b-7b")
     assert c.num_experts == 64 and c.experts_per_token == 8
+    assert c.router_scoring == "softmax" and c.first_k_dense_replace == 0
     c = get_config("h2o-danube-1.8b")
     assert c.sliding_window is not None
     c = get_config("zamba2-2.7b")

@@ -204,17 +204,20 @@ def test_decode_step_cache_is_donated():
 
 
 def test_moe_prefill_matches_stepwise_decode():
-    """MoE prefill is exact-length (no pad tokens stealing expert capacity)
-    and matches stepwise decode when capacity doesn't drop."""
+    """MoE prefill pads to a bucket (the serving expert layer drops no
+    token, so pad rows take nothing from real ones) and matches stepwise
+    decode."""
     cfg = dataclasses.replace(get_reduced("olmoe-1b-7b"), dtype="float32",
-                              capacity_factor=64.0)
+                              capacity_factor=1.0)
     m = build(cfg)
-    assert not m.padded_prefill
+    assert m.padded_prefill
     params = m.init(jax.random.PRNGKey(0))
     prompt = np.array([5, 9, 2, 7, 1], np.int32)
     slots, max_len, slot = 2, 16, 0
     cache = m.init_cache(slots, max_len, dtype=jnp.float32)
-    lg_pre, _ = m.prefill(params, jnp.asarray(prompt)[None, :], cache,
+    padded = np.zeros(8, np.int32)
+    padded[:len(prompt)] = prompt
+    lg_pre, _ = m.prefill(params, jnp.asarray(padded)[None, :], cache,
                           jnp.asarray(slot, jnp.int32),
                           jnp.asarray(len(prompt), jnp.int32))
     cache2 = m.init_cache(slots, max_len, dtype=jnp.float32)

@@ -154,6 +154,43 @@ def test_qwen2_full_width_compressed_decode_compiles_for_v5e(
             < 16 * 10**9), mem
 
 
+def test_deepseek_ep32_share_compressed_decode_compiles_for_v5e(
+        one_chip, tpu_routing):
+    """DeepSeek-V3 at its published widths as one chip's share of 32-way
+    expert parallelism (3 dense + 4 MoE layers, 8 held experts, a 16160-row
+    vocabulary slice), FORMS m=8 / 8-bit, 128 slots of 1024 in 16-row
+    pages: the paged decode step holds one kernel per compressed matmul a
+    scan body calls and fits one chip."""
+    import dataclasses
+    slots, max_len, page = 128, 1024, 16
+    cfg = dataclasses.replace(get_config("deepseek-v3-671b"), num_layers=7,
+                              experts_held=8, vocab_size=16160, mtp=False)
+    model = build(cfg)
+    spec = FormsSpec(m=8, bits=8)
+    params = _compressed_shapes(
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)), spec)
+    cache = jax.eval_shape(functools.partial(
+        model.init_paged_cache, slots * max_len // page + 1, page, slots,
+        max_len))
+    args = (params, jax.ShapeDtypeStruct((slots, 1), jnp.int32), cache,
+            jax.ShapeDtypeStruct((slots,), jnp.int32),
+            jax.ShapeDtypeStruct((slots, max_len // page), jnp.int32))
+
+    def fn(*a):
+        with default_spec(spec):
+            return model.decode_paged(*a)
+
+    compiled = jax.jit(fn, donate_argnums=(2,)).lower(
+        *_on(one_chip, args)).compile()
+    # dense layers: q_down, q_up, kv_down, wo, gate, up, down; MoE layers:
+    # the same MLA four, the held experts' gate, up, down (one scan over
+    # the experts) and the shared expert's three; the head
+    assert compiled.as_text().count(KERNEL) == 7 + 10 + 1
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 10**9), mem
+
+
 @pytest.mark.parametrize("arch,window", [("qwen2", None), ("danube", 4096)])
 def test_paged_attention_compiles_for_v5e(one_chip, tpu_routing, arch,
                                           window):
